@@ -10,7 +10,7 @@ local ``$arm`` reference for the serve arms), deliberately avoiding a
 ``jsonschema`` dependency.
 
 ``required`` lists only keys common to quick (CI smoke) and full runs;
-full-only sections (``coldstart``, hier-row extras) are validated when
+full-only extras (hier-row fields) are validated when
 present.  JSON has one number type, so ``number`` accepts ints while
 ``integer`` rejects floats with a fractional part.
 

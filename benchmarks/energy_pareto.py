@@ -65,6 +65,7 @@ import numpy as np
 
 import jax
 
+from repro.compile_cache import use_compile_cache
 from repro.core import PiecewiseLinearFPM, SpeedStore
 from repro.core.energy import energy_model
 from repro.fleet import FleetScheduler, JobSpec
@@ -387,6 +388,7 @@ def main(argv=None) -> int:
                     help="CI smoke: small p sweep + the QUICK trace")
     ap.add_argument("--out", default="BENCH_energy.json")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     # benchmark-process only (the test suite imports serve_trace; flipping
     # x64 at import time would change every other test)

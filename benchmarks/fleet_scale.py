@@ -38,12 +38,6 @@ cached ``[g, k_g]`` aggregates + one cache-blocked inner program per job)
 of CPU cache and loses to sequential (the seed measured 0.45x); the
 two-level route must recover it to >= 1.0x (gated).
 
-Cold-start rows (full mode): wall-clock from process start to the first
-partition of a warm-admitted job, measured in a SUBPROCESS so jit tracing
-is genuinely cold, with ``compilation_cache_dir=`` pointed at a shared
-directory — run twice: the second run loads compiled kernels from the
-persistent cache instead of re-tracing.
-
 Acceptance gates (exit 1):
   * full mode — at every q >= 16: the stacked driver issues >= q x fewer
     device dispatches per round (all p), and the steady-state rebalance
@@ -81,17 +75,15 @@ import numpy as np
 
 import jax
 
-# Bit-identical-to-sequential is the parity gate; that needs doubles.
-jax.config.update("jax_enable_x64", True)
-
-from repro.core import (  # noqa: E402
+from repro.compile_cache import use_compile_cache
+from repro.core import (
     BatchedSimulatedExecutor2D,
     PiecewiseLinearFPM,
     Scheduler,
     SimulatedExecutor,
     SpeedStore,
 )
-from repro.fleet import FleetScheduler, JobSpec  # noqa: E402
+from repro.fleet import FleetScheduler, JobSpec
 
 
 def make_tenants(q: int, p: int, seed: int = 0):
@@ -612,64 +604,6 @@ def bucket_gate(p=50, seed=41) -> bool:
     return ok
 
 
-_COLDSTART_WORKER = r"""
-import sys, time
-t0 = time.perf_counter()
-import numpy as np
-from repro.core import PiecewiseLinearFPM
-from repro.fleet import FleetScheduler, JobSpec
-
-p, cache_dir = int(sys.argv[1]), sys.argv[2]
-rng = np.random.default_rng(0)
-base = rng.uniform(1e-6, 3e-6, p)
-knee = rng.uniform(2e3, 2e4, p)
-warm = []
-for i in range(p):
-    xs = np.geomspace(16.0, 8.0 * knee[i], 6)
-    ts = xs * base[i] * (
-        1.0 + np.where(xs > knee[i], 3.0 * (xs - knee[i]) / knee[i], 0.0)
-    )
-    warm.append(PiecewiseLinearFPM.from_points(list(zip(xs, xs / ts))))
-fleet = FleetScheduler(p, backend="jax", compilation_cache_dir=cache_dir)
-fleet.admit(JobSpec(name="t0", n=100 * p, eps=1e-12, min_units=1), models=warm)
-fleet.rebalance({"t0": 100 * p})
-print("COLDSTART_MS", (time.perf_counter() - t0) * 1e3)
-"""
-
-
-def coldstart_first_partition(p=1000):
-    """Wall-clock from interpreter start to the first partition of a
-    warm-admitted job, in a fresh subprocess (cold jit traces), with the
-    persistent compilation cache dir shared between two runs: the second
-    run's compiles load from disk."""
-    import os
-    import subprocess
-    import sys
-    import tempfile
-
-    def run_once(cache_dir):
-        env = dict(os.environ)
-        env["PYTHONPATH"] = "src"
-        out = subprocess.run(
-            [sys.executable, "-c", _COLDSTART_WORKER, str(p), cache_dir],
-            capture_output=True, text=True, env=env, timeout=600,
-        )
-        for line in out.stdout.splitlines():
-            if line.startswith("COLDSTART_MS"):
-                return float(line.split()[1])
-        raise RuntimeError(f"coldstart worker failed: {out.stderr[-2000:]}")
-
-    with tempfile.TemporaryDirectory(prefix="jaxcache_") as d:
-        cold = run_once(d)
-        warm = run_once(d)
-    return {
-        "p": p,
-        "coldstart_first_partition_ms": cold,
-        "coldstart_cached_ms": warm,
-        "coldstart_cache_speedup": cold / warm,
-    }
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--quick", action="store_true", help="CI smoke: parity gate + small sweep")
@@ -679,6 +613,9 @@ def main(argv=None) -> int:
                     help="export the obs-overhead regime's telemetry as a "
                          "Chrome-trace JSON (open in chrome://tracing)")
     args = ap.parse_args(argv)
+    use_compile_cache()
+    # Bit-identical-to-sequential is the parity gate; that needs doubles.
+    jax.config.update("jax_enable_x64", True)
 
     if args.quick:
         sweep = [(1, 100), (8, 100)]
@@ -788,15 +725,6 @@ def main(argv=None) -> int:
         export_chrome_trace(obs_tel, args.trace)
         print(f"-> {args.trace}")
 
-    coldstart = None
-    if not args.quick:
-        print("cold-start (p=1000, fresh subprocess, shared compilation "
-              "cache) ...", flush=True)
-        coldstart = coldstart_first_partition(p=1000)
-        print(f"  cold {coldstart['coldstart_first_partition_ms']:.0f} ms, "
-              f"cached {coldstart['coldstart_cached_ms']:.0f} ms "
-              f"({coldstart['coldstart_cache_speedup']:.2f}x)", flush=True)
-
     print("parity gate (q=8, p=100, noise-free) ...", flush=True)
     parity_ok = parity_gate()
     print("parity:", "OK" if parity_ok else "FAIL")
@@ -846,8 +774,6 @@ def main(argv=None) -> int:
         "sweep": rows,
         "obs_overhead": obs_row,
     }
-    if coldstart is not None:
-        payload["coldstart"] = coldstart
     with open(args.out, "w") as f:
         json.dump(payload, f, indent=2)
     print(f"-> {args.out}")

@@ -50,13 +50,9 @@ trading exactness for locality: ``hier_makespan_ratio`` (two-level vs flat
 makespan) is gated <= 1.12 at every swept p, matching the fuzz-test
 envelope (empirical worst over 340 random monotone fleets is ~1.10).
 
-p=10^6 row (full sweep): a subprocess with
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` builds eight
-125k-processor group banks via ``Hierarchy.from_group_banks`` (the flat
-``[p, k]`` bank is never materialized) and repartitions n=20p units under
-``sharding="shard_map"``.  Gates: the allocation sums to n, and
-``max_shard_elems`` — the largest bank block any one device holds — is
->= 4x smaller than the flat bank (expected 8x with 8 emulated devices).
+The p=10^6 row (eight 125k-processor group banks under
+``sharding="shard_map"``) runs on a four-chip host as
+``chip_smoke.py --four-chip``.
 
 The jax sweep runs with x64 enabled and asserts its allocations are
 BIT-IDENTICAL to the numpy bank at every swept p (exit code 1 otherwise —
@@ -72,13 +68,11 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
-import subprocess
-import sys
 import time
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import Hierarchy, ModelBank, PiecewiseLinearFPM, SpeedStore
 from repro.core.partition import _partition_units_bank, _prep_unit_caps
 
@@ -307,7 +301,7 @@ def run_sweep(ps, repeats: int, backend: str, units_per_proc: int = 100,
             # Two-level route over the same fleet: groups sized to keep each
             # inner block cache-resident.  Near-optimal (gated <= 1.12x flat
             # makespan), and the only route that scales past the flat bank's
-            # memory wall — see the p=10^6 subprocess row.
+            # memory wall — see the p=10^6 row in chip_smoke.py.
             gsize = 100 if p <= 1000 else 1000
             groups = (np.arange(p) // gsize).tolist()
             caps_np = np.asarray(icaps, dtype=np.int64)
@@ -376,75 +370,14 @@ def run_sweep(ps, repeats: int, backend: str, units_per_proc: int = 100,
     return rows
 
 
-def _p1e6_row() -> dict:
-    """Worker for the p=10^6 row — run in a subprocess with
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` set BEFORE jax
-    imports (device count is fixed at first import)."""
-    import jax
-
-    jax.config.update("jax_enable_x64", True)
-    g, p_g = 8, 125_000
-    p = g * p_g
-    t0 = time.perf_counter()
-    banks = [make_fleet_bank(p_g, seed=1000 + i) for i in range(g)]
-    h = Hierarchy.from_group_banks(banks, backend="jax", sharding="shard_map")
-    t_build = time.perf_counter() - t0
-    n = 20 * p
-    caps = np.full(p, n, dtype=np.int64)  # uncapped, vectorized-validation path
-    t0 = time.perf_counter()
-    d = h.partition_units(n, caps, min_units=1)
-    t_first = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    d = h.partition_units(n, caps, min_units=1)
-    t_steady = time.perf_counter() - t0
-    return {
-        "p": p,
-        "g": g,
-        "n": n,
-        "ndev": len(jax.devices()),
-        "build_s": t_build,
-        "first_call_s": t_first,
-        "steady_s": t_steady,
-        "max_shard_elems": int(h.max_shard_elems()),
-        "flat_bank_elems": 2 * p * 6,
-        "sum_equals_n": int(np.sum(np.asarray(d, dtype=np.int64))) == n,
-    }
-
-
-def run_p1e6_subprocess() -> dict | None:
-    """Launch :func:`_p1e6_row` in a fresh interpreter with 8 emulated XLA
-    host devices.  Returns the row dict, or None on failure."""
-    here = os.path.dirname(os.path.abspath(__file__))
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(here, "..", "src"), env.get("PYTHONPATH", "")]
-    )
-    proc = subprocess.run(
-        [sys.executable, os.path.abspath(__file__), "--p1e6-row"],
-        env=env,
-        capture_output=True,
-        text=True,
-    )
-    for line in proc.stdout.splitlines():
-        if line.startswith("P1E6_ROW "):
-            return json.loads(line[len("P1E6_ROW "):])
-    print("p=10^6 subprocess failed:", proc.stdout[-1000:], proc.stderr[-1000:])
-    return None
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--quick", action="store_true", help="small sweep for CI smoke")
     ap.add_argument("--backend", choices=["numpy", "jax", "both"], default="both")
     ap.add_argument("--out", default="BENCH_partition.json")
     ap.add_argument("--repeats", type=int, default=None)
-    ap.add_argument("--p1e6-row", action="store_true", help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
-
-    if args.p1e6_row:
-        print("P1E6_ROW " + json.dumps(_p1e6_row()), flush=True)
-        return 0
+    use_compile_cache()
 
     if args.quick:
         # p=1000 is included so the p==1000 acceptance gates (facade tax,
@@ -464,22 +397,6 @@ def main(argv=None) -> int:
 
     rows = run_sweep(ps, repeats, args.backend, scalar_cutoff=cutoff, f32_ps=f32_ps)
 
-    p1e6 = None
-    if not args.quick and args.backend in ("jax", "both"):
-        print("p=10^6 hier shard_map row (subprocess, 8 emulated devices) ...",
-              flush=True)
-        p1e6 = run_p1e6_subprocess()
-        if p1e6 is not None:
-            print(
-                f"p={p1e6['p']}  build={p1e6['build_s']:.2f} s"
-                f"  first={p1e6['first_call_s']:.1f} s"
-                f"  steady={p1e6['steady_s']:.1f} s"
-                f"  shard_elems={p1e6['max_shard_elems']:,} vs flat "
-                f"{p1e6['flat_bank_elems']:,}"
-                f"  sum==n: {p1e6['sum_equals_n']}",
-                flush=True,
-            )
-
     payload = {
         "benchmark": "partition_scale",
         "description": (
@@ -492,18 +409,13 @@ def main(argv=None) -> int:
             "with jax_completion_speedup the fast-vs-per-unit ratio gated "
             ">=10x at p=10^5; jax_f32_* columns quantify float32 drift at "
             "p=10^4 and p=10^5; hier_* columns time the two-level Hierarchy "
-            "route at p>=1000 with its makespan gated <= 1.12x flat; the "
-            "p1e6 block is the from_group_banks + shard_map feasibility row "
-            "on 8 emulated devices, gated on sum==n and >=4x smaller "
-            "per-device bank blocks than flat)"
+            "route at p>=1000 with its makespan gated <= 1.12x flat)"
         ),
         "units_per_proc": 100,
         "repeats": repeats,
         "backend": args.backend,
         "sweep": rows,
     }
-    if p1e6 is not None:
-        payload["p1e6"] = p1e6
     with open(args.out, "w") as f:
         json.dump(payload, f, indent=2)
     print(f"-> {args.out}")
@@ -583,21 +495,6 @@ def main(argv=None) -> int:
         print("FAIL: hierarchical makespan > 1.12x flat:",
               [(r["p"], round(r["hier_makespan_ratio"], 4)) for r in bad_hier])
         rc = 1
-    # p=10^6 feasibility gates: the allocation is exact in total, and
-    # shard_map actually bounds per-device memory (8 emulated devices ->
-    # expect 8x; gate at >= 4x so a device-count drop to 4 still passes).
-    if not args.quick and args.backend in ("jax", "both"):
-        if p1e6 is None:
-            print("FAIL: p=10^6 row did not run")
-            rc = 1
-        else:
-            if not p1e6["sum_equals_n"]:
-                print("FAIL: p=10^6 hier allocation does not sum to n")
-                rc = 1
-            if p1e6["max_shard_elems"] * 4 > p1e6["flat_bank_elems"]:
-                print(f"FAIL: p=10^6 per-shard bank {p1e6['max_shard_elems']:,}"
-                      f" elems not >=4x below flat {p1e6['flat_bank_elems']:,}")
-                rc = 1
     return rc
 
 

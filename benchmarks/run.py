@@ -11,6 +11,8 @@ import os
 import sys
 import time
 
+from repro.compile_cache import use_compile_cache
+
 from . import paper_tables
 from .kernels_bench import kernels_bench
 from .roofline import roofline_table
@@ -32,6 +34,7 @@ def main(argv=None) -> int:
     ap.add_argument("--only", default=None)
     ap.add_argument("--out", default="experiments/bench")
     args = ap.parse_args(argv)
+    use_compile_cache()
     os.makedirs(args.out, exist_ok=True)
     rc = 0
     for name, fn in ARTIFACTS:

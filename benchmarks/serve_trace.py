@@ -80,6 +80,7 @@ import numpy as np
 
 import jax
 
+from repro.compile_cache import use_compile_cache
 from repro.core.executor import FleetRoundLog
 from repro.fleet import ProfileRegistry
 from repro.runtime.serve_loop import ReplicaDispatcher
@@ -645,6 +646,7 @@ def main(argv=None) -> int:
                     help="write a Chrome-trace JSON of the adaptive arm "
                          "(+ TRACE.flightrec.json on incidents)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     # benchmark-process only (NOT at import: the test suite imports this
     # module, and flipping x64 mid-process would change every other test)

@@ -68,6 +68,17 @@ class FleetRoundLog:
     t_wall: float = field(default=0.0, compare=False)  # see RoundLog.t_wall
 
 
+def _total_cost(logs) -> float:
+    """Left-to-right sum of the logged round costs: the order in which
+    ``FleetScheduler`` accumulates a job's ``bench_cost``.  (``sum()`` of
+    floats is compensated since Python 3.12 and can differ in the last
+    bit.)"""
+    total = 0.0
+    for log in logs:
+        total += log.wall_cost
+    return total
+
+
 class Executor(Protocol):
     @property
     def num_procs(self) -> int: ...
@@ -135,7 +146,7 @@ class SimulatedExecutor:
 
     @property
     def total_cost(self) -> float:
-        return sum(l.wall_cost for l in self.logs)
+        return _total_cost(self.logs)
 
 
 @dataclass
@@ -176,7 +187,7 @@ class BatchedSimulatedExecutor:
 
     @property
     def total_cost(self) -> float:
-        return sum(l.wall_cost for l in self.logs)
+        return _total_cost(self.logs)
 
 
 @dataclass
@@ -250,7 +261,7 @@ class BatchedSimulatedExecutor2D:
 
     @property
     def total_cost(self) -> float:
-        return sum(l.wall_cost for l in self.logs)
+        return _total_cost(self.logs)
 
 
 @dataclass
@@ -390,7 +401,7 @@ class TraceExecutor2D:
 
     @property
     def total_cost(self) -> float:
-        return sum(l.wall_cost for l in self.logs)
+        return _total_cost(self.logs)
 
 
 @dataclass
@@ -425,4 +436,4 @@ class CallableExecutor:
 
     @property
     def total_cost(self) -> float:
-        return sum(l.wall_cost for l in self.logs)
+        return _total_cost(self.logs)

@@ -71,8 +71,10 @@ __all__ = ["Hierarchy"]
 # Compiled shard_map'd inner solvers, keyed by (device count, completion
 # routing, max_steps).  Module-level so rebuilding a Hierarchy (every
 # observation fold changes the banks) never retraces: jax.jit's own cache
-# handles shape changes, and the mesh is built once per device count.
+# handles shape changes, and the mesh is built once per device count
+# (_SHARDING_CACHE).
 _SHARD_FN_CACHE: dict = {}
+_SHARDING_CACHE: dict = {}
 
 # Inner-solve execution routing: batched (one masked [g, ...] bisection)
 # while the xs+ss block set a device touches fits comfortably in L2-ish
@@ -88,6 +90,19 @@ _HIER_BATCH_MAX_BYTES = 2 * 1024 * 1024
 _AGG_DEVICE_MAX_BYTES = 256 * 1024 * 1024
 
 
+def _groups_sharding(ndev: int):
+    """Sharding of a ``[g, ...]`` group-block array over the first ``ndev``
+    devices, split along the group axis."""
+    sharding = _SHARDING_CACHE.get(ndev)
+    if sharding is None:
+        import jax
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+        mesh = Mesh(np.array(jax.devices()[:ndev]), ("groups",))
+        sharding = _SHARDING_CACHE[ndev] = NamedSharding(mesh, P("groups"))
+    return sharding
+
+
 def _shard_inner_fn(ndev: int, completion_fast: bool, max_steps: int, serial: bool):
     key = (ndev, completion_fast, max_steps, serial)
     fn = _SHARD_FN_CACHE.get(key)
@@ -95,13 +110,11 @@ def _shard_inner_fn(ndev: int, completion_fast: bool, max_steps: int, serial: bo
         from functools import partial
 
         import jax
-        from jax.experimental.shard_map import shard_map
-        from jax.sharding import Mesh, PartitionSpec as P
 
         from .modelbank_jax import _hier_inner_map
 
-        mesh = Mesh(np.array(jax.devices()[:ndev]), ("groups",))
-        spec = P("groups")
+        sharding = _groups_sharding(ndev)
+        spec = sharding.spec
         body = partial(
             _hier_inner_map,
             rel_tol=1e-12,
@@ -109,16 +122,17 @@ def _shard_inner_fn(ndev: int, completion_fast: bool, max_steps: int, serial: bo
             completion_fast=completion_fast,
             serial=serial,
         )
-        # check_rep=False: the bisection while_loops have no replication rule
-        # (jax 0.4.x); sound here because the body is collective-free — every
-        # output is fully sharded along "groups", nothing is replicated.
+        # check_vma=False: the body is collective-free and every output is
+        # fully sharded along "groups", so there is no varying-axis
+        # information for JAX to check; skipping the check keeps the
+        # while_loop bodies free of pvary annotations.
         fn = jax.jit(
-            shard_map(
+            jax.shard_map(
                 body,
-                mesh=mesh,
+                mesh=sharding.mesh,
                 in_specs=(spec,) * 7,
                 out_specs=(spec,) * 3,
-                check_rep=False,
+                check_vma=False,
             )
         )
         _SHARD_FN_CACHE[key] = fn
@@ -168,6 +182,8 @@ class Hierarchy:
         self._blocks = None  # device [g, p_max, k] blocks, built lazily
         self._blocks_pad = None  # shard-padded variant, keyed by ndev
         self._agg_cache: dict = {}  # caps signature -> aggregated group bank
+        # devices that held the last jax inner solve's result
+        self.inner_devices: frozenset = frozenset()
 
     # -- construction --------------------------------------------------------
 
@@ -248,19 +264,27 @@ class Hierarchy:
 
     def max_shard_elems(self) -> int:
         """Largest number of bank elements (xs plus ss knots) any single
-        device materializes for the inner solves — the memory gate of the
-        p=10^6 benchmark row.  Under ``shard_map`` each device holds only its
-        ``ceil(g/ndev)`` group blocks; otherwise the one device (or host)
-        holds all ``g``."""
-        p_max = max((b.p for b in self.sub_banks), default=1) or 1
-        k = max((int(b.xs.shape[1]) for b in self.sub_banks), default=1)
-        lanes = self.g
-        if self.backend == "jax" and self.sharding == "shard_map":
+        device holds for the inner solves — the memory gate of the p=10^6
+        row.  On the jax backend it is read from where the group blocks
+        actually live: under ``shard_map`` each device should hold only its
+        ``ceil(g/ndev)`` blocks; otherwise one device holds all ``g``.  On
+        the numpy backend the host holds all ``g``."""
+        if self.backend != "jax":
+            p_max = max((b.p for b in self.sub_banks), default=1) or 1
+            k = max((int(b.xs.shape[1]) for b in self.sub_banks), default=1)
+            return 2 * self.g * p_max * k
+        if self.sharding == "shard_map":
             import jax
 
-            ndev = max(len(jax.devices()), 1)
-            lanes = -(-self.g // ndev)
-        return 2 * lanes * p_max * k
+            xs, ss, _, _ = self._padded_blocks(max(len(jax.devices()), 1))
+        else:
+            xs, ss, _ = self._ensure_blocks()
+        per_device: dict = {}
+        for arr in (xs, ss):
+            for shard in arr.addressable_shards:
+                size = int(np.prod(shard.data.shape))
+                per_device[shard.device] = per_device.get(shard.device, 0) + size
+        return max(per_device.values())
 
     # -- the two-level solve -------------------------------------------------
 
@@ -512,28 +536,34 @@ class Hierarchy:
 
     # -- jax inner solves ----------------------------------------------------
 
+    def _host_blocks(self, pad: int = 0):
+        """The ``[g + pad, p_max, k]`` group blocks as host arrays; the
+        ``pad`` trailing lanes are inert (counts 0)."""
+        g = self.g
+        p_max = max((b.p for b in self.sub_banks), default=0) or 1
+        k = max((int(b.xs.shape[1]) for b in self.sub_banks), default=1)
+        xs = np.zeros((g + pad, p_max, k), dtype=np.float64)
+        ss = np.zeros_like(xs)
+        counts = np.zeros((g + pad, p_max), dtype=np.int64)
+        for gi, b in enumerate(self.sub_banks):
+            pg, kb = b.xs.shape
+            if pg == 0:
+                continue
+            xs[gi, :pg, :kb] = b.xs
+            ss[gi, :pg, :kb] = b.ss
+            if kb < k:
+                # width padding repeats the last column, the
+                # from_point_lists convention (masked by counts anyway)
+                xs[gi, :pg, kb:] = b.xs[:, -1:]
+                ss[gi, :pg, kb:] = b.ss[:, -1:]
+            counts[gi, :pg] = b.counts
+        return xs, ss, counts
+
     def _ensure_blocks(self):
         if self._blocks is None:
             import jax.numpy as jnp
 
-            g = self.g
-            p_max = max((b.p for b in self.sub_banks), default=0) or 1
-            k = max((int(b.xs.shape[1]) for b in self.sub_banks), default=1)
-            xs = np.zeros((g, p_max, k), dtype=np.float64)
-            ss = np.zeros_like(xs)
-            counts = np.zeros((g, p_max), dtype=np.int64)
-            for gi, b in enumerate(self.sub_banks):
-                pg, kb = b.xs.shape
-                if pg == 0:
-                    continue
-                xs[gi, :pg, :kb] = b.xs
-                ss[gi, :pg, :kb] = b.ss
-                if kb < k:
-                    # width padding repeats the last column, the
-                    # from_point_lists convention (masked by counts anyway)
-                    xs[gi, :pg, kb:] = b.xs[:, -1:]
-                    ss[gi, :pg, kb:] = b.ss[:, -1:]
-                counts[gi, :pg] = b.counts
+            xs, ss, counts = self._host_blocks()
             self._blocks = (
                 jnp.asarray(xs, dtype=self.dtype),
                 jnp.asarray(ss, dtype=self.dtype),
@@ -544,22 +574,20 @@ class Hierarchy:
     def _padded_blocks(self, ndev: int):
         """Group blocks with ``g`` padded up to a multiple of ``ndev`` by
         inert zero lanes (counts 0 — their caps/shares are zeroed by the
-        caller), so shard_map's even split always applies."""
-        xs, ss, counts = self._ensure_blocks()
-        g = int(counts.shape[0])
-        pad = (-g) % ndev
-        if pad == 0:
-            return xs, ss, counts, 0
+        caller), so shard_map's even split always applies.  They are placed
+        straight from the host onto their devices, ``ceil(g/ndev)`` blocks
+        each: no device ever holds the whole bank."""
+        pad = (-self.g) % ndev
         if self._blocks_pad is None or self._blocks_pad[0] != ndev:
-            import jax.numpy as jnp
+            import jax
 
-            zf = jnp.zeros((pad,) + tuple(xs.shape[1:]), dtype=xs.dtype)
-            zc = jnp.zeros((pad,) + tuple(counts.shape[1:]), dtype=counts.dtype)
+            sharding = _groups_sharding(ndev)
+            xs, ss, counts = self._host_blocks(pad)
             self._blocks_pad = (
                 ndev,
-                jnp.concatenate([xs, zf]),
-                jnp.concatenate([ss, zf]),
-                jnp.concatenate([counts, zc]),
+                jax.device_put(np.asarray(xs, dtype=self.dtype), sharding),
+                jax.device_put(np.asarray(ss, dtype=self.dtype), sharding),
+                jax.device_put(counts, sharding),
             )
         _, xs_p, ss_p, counts_p = self._blocks_pad
         return xs_p, ss_p, counts_p, pad
@@ -610,15 +638,17 @@ class Hierarchy:
                 n_blk = np.concatenate([n_blk, np.zeros(pad, dtype=np.int64)])
                 fast = np.concatenate([fast, np.zeros(pad, dtype=bool)])
             fn = _shard_inner_fn(ndev, cf, max_steps, serial)
+            # host operands: jit places each on its group shard directly
             d, ok, _t = fn(
                 xs,
                 ss,
                 counts,
-                jnp.asarray(caps_blk, counts.dtype),
-                jnp.asarray(n_blk),
-                jnp.asarray(mu_blk, counts.dtype),
-                jnp.asarray(fast),
+                caps_blk.astype(counts.dtype),
+                n_blk,
+                mu_blk.astype(counts.dtype),
+                fast,
             )
+            self.inner_devices = frozenset(d.sharding.device_set)
             d = np.asarray(d)[:g]
             ok = np.asarray(ok)[:g]
         else:
@@ -636,6 +666,7 @@ class Hierarchy:
                 completion_fast=cf,
                 serial=2 * int(xs.size) * itemsize > _HIER_BATCH_MAX_BYTES,
             )
+            self.inner_devices = frozenset(d.sharding.device_set)
             d = np.asarray(d)
             ok = np.asarray(ok)
         if not bool(np.all(ok)):

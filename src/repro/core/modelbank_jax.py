@@ -27,7 +27,7 @@ ops.  The ``t*`` search of the geometric partitioner runs entirely on device:
 
 Every formula mirrors the numpy implementation expression-for-expression;
 with float64 enabled (``jax.config.update("jax_enable_x64", True)`` or the
-``jax.experimental.enable_x64`` context) the element-wise ops are IEEE-double
+``jax.enable_x64()`` context) the element-wise ops are IEEE-double
 identical to numpy, so allocations match the numpy bank bit-for-bit (the
 acceptance gate of ``benchmarks/partition_scale.py --backend jax``).  Without
 x64 the math runs in float32 and allocations may differ by a unit — fine for
@@ -41,8 +41,9 @@ silent upcasts/downcasts occur inside ``jit``.
 ``BalanceController`` keep the bank as a *device-resident carry* across
 rounds — one ``[p]``-wide masked shift per round instead of rebuilding the
 padded arrays from ``p`` scalar models (the ROADMAP's observation fold-in
-item).  The carry buffers are donated to the update where the backend
-supports donation (no-op on CPU).
+item).  The carry buffers are donated to the update: the bank passed to
+``fold_in`` is spent, and a holder that must keep reading it takes a
+``copy()`` first (or folds with ``donate=False``).
 """
 
 from __future__ import annotations
@@ -60,31 +61,7 @@ from jax import lax
 
 from .modelbank import ModelBank
 
-__all__ = ["JaxModelBank", "enable_compilation_cache", "fetch_partition"]
-
-
-def enable_compilation_cache(path: str) -> None:
-    """Point jax's persistent compilation cache at ``path`` so a restarted
-    session (or a cold CI runner) reuses compiled partition/fold kernels
-    instead of re-tracing them — the Scheduler/FleetScheduler
-    ``compilation_cache_dir=`` knob.  Idempotent; safe to call per session."""
-    from jax.experimental.compilation_cache import compilation_cache as cc
-
-    cc.set_cache_dir(str(path))
-    try:
-        # Our kernels compile in ~1-3s each; cache them all, not just the
-        # ones above jax's default write threshold.
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except Exception:  # pragma: no cover - knob name varies across versions
-        pass
-
-# Buffer donation is a no-op (and warns) on CPU; donate the fold-in carry
-# only where the platform actually reuses the buffers.  When donation is on,
-# fold_in invalidates the previous bank's buffers — holders of snapshots
-# (e.g. BalanceController.device_bank callers) must copy() first;
-# DONATES_CARRY tells them whether that matters on this platform.
-_DONATE = (0, 1, 2) if jax.default_backend() != "cpu" else ()
-DONATES_CARRY = bool(_DONATE)
+__all__ = ["JaxModelBank", "fetch_partition"]
 
 
 # ---------------------------------------------------------------------------
@@ -654,12 +631,14 @@ def _fold_in_impl(xs, ss, counts, x, s, valid):
     )
 
 
-_fold_in_jit = partial(jax.jit, donate_argnums=_DONATE)(_fold_in_impl)
+# The carry (xs, ss, counts) is donated: every backend reuses its buffers
+# for the output, and the bank that was folded is spent.
+_fold_in_jit = partial(jax.jit, donate_argnums=(0, 1, 2))(_fold_in_impl)
 # Non-donating twin: double-buffered callers (the fleet's pipelined rounds)
 # fold into a NEW carry while the previous generation's buffers stay valid,
-# so an in-flight repartition can keep reading them.  On CPU (no donation)
-# the two behave identically; keeping separate jit caches means a pipelined
-# fleet never perturbs the donating path's recompile accounting.
+# so an in-flight repartition can keep reading them.  Keeping separate jit
+# caches means a pipelined fleet never perturbs the donating path's
+# recompile accounting.
 _fold_in_nodonate_jit = jax.jit(_fold_in_impl)
 
 
@@ -856,17 +835,15 @@ class JaxModelBank:
     def scaled(self, speed_scale) -> "JaxModelBank":
         """New bank with every row's speeds scaled (2-D column-width rescale).
 
-        Where ``fold_in`` donates its carry the shared ``xs``/``counts``
-        buffers are copied, so folding either bank cannot invalidate the
-        other; on CPU they alias harmlessly.
+        ``fold_in`` donates its carry, so the ``xs``/``counts`` buffers are
+        copied: folding either bank cannot invalidate the other.
         """
         scale_host = np.asarray(speed_scale, dtype=np.float64)
         scale = jnp.broadcast_to(jnp.asarray(speed_scale, self.dtype), self.counts.shape)
-        xs = jnp.array(self.xs) if DONATES_CARRY else self.xs
-        counts = jnp.array(self.counts) if DONATES_CARRY else self.counts
         positive = bool(np.all(scale_host > 0.0))
         return JaxModelBank(
-            xs=xs, ss=self.ss * scale[..., None], counts=counts,
+            xs=jnp.array(self.xs), ss=self.ss * scale[..., None],
+            counts=jnp.array(self.counts),
             max_count=self.max_count, empty_rows=self.empty_rows,
             # positive per-row scaling preserves time-monotonicity
             monotone=self.monotone if positive else None,
@@ -876,9 +853,9 @@ class JaxModelBank:
         )
 
     def copy(self) -> "JaxModelBank":
-        """Deep copy of the device buffers.  Needed by holders of a snapshot
-        on platforms where ``fold_in`` donates its carry (``DONATES_CARRY``):
-        the original buffers are invalidated by the next fold."""
+        """Deep copy of the device buffers.  Needed by holders of a snapshot:
+        ``fold_in`` donates its carry, so the next fold invalidates the
+        original buffers."""
         return JaxModelBank(
             xs=jnp.array(self.xs), ss=jnp.array(self.ss),
             counts=jnp.array(self.counts), max_count=self.max_count,
@@ -1109,7 +1086,7 @@ class JaxModelBank:
     def fold_in(self, x, s, valid=None, *, donate: bool = True) -> "JaxModelBank":
         """Insert one observation ``(x_i, s_i)`` per row (vectorized sorted
         insert; duplicate ``x`` replaces the speed).  Returns the updated
-        bank; the old buffers are donated where the platform supports it.
+        bank; the old buffers are donated to it, so this bank is spent.
         Grows the padded width (by doubling) when any row is full.
 
         ``donate=False`` routes through a non-donating twin of the fold

@@ -194,7 +194,6 @@ class Scheduler:
         groups: Optional[Sequence[int]] = None,
         sharding: Optional[str] = None,
         max_group_knots: int = 64,
-        compilation_cache_dir: Optional[str] = None,
     ):
         if backend not in ("scalar", "numpy", "jax"):
             raise ValueError(f"unknown backend {backend!r}")
@@ -236,11 +235,6 @@ class Scheduler:
         )
         self.sharding = sharding
         self.max_group_knots = int(max_group_knots)
-        self.compilation_cache_dir = compilation_cache_dir
-        if compilation_cache_dir is not None and backend == "jax":
-            from .modelbank_jax import enable_compilation_cache
-
-            enable_compilation_cache(compilation_cache_dir)
         # online state
         self.d: List[int] = (
             _even(self.n_units, self.num_groups)

@@ -374,10 +374,10 @@ class SpeedStore:
     def device_bank(self, *, snapshot: bool = True):
         """``JaxModelBank`` view.  On the jax backend this is the
         incrementally maintained carry; otherwise built from the models on
-        demand.  With ``snapshot=True`` the result is copied on platforms
-        where ``fold_in`` donates its carry, so later folds cannot invalidate
-        the caller's reference."""
-        from .modelbank_jax import DONATES_CARRY, JaxModelBank
+        demand.  With ``snapshot=True`` the result is a copy: ``fold_in``
+        donates its carry, so later folds cannot invalidate the caller's
+        reference."""
+        from .modelbank_jax import JaxModelBank
 
         if self.backend == "jax":
             jb = self._carry()
@@ -385,7 +385,7 @@ class SpeedStore:
             jb = JaxModelBank.from_bank(self._np_bank, dtype=self.dtype)
         else:
             jb = JaxModelBank.from_models(self.models, dtype=self.dtype)
-        return jb.copy() if (snapshot and DONATES_CARRY) else jb
+        return jb.copy() if snapshot else jb
 
     def drop_carry(self) -> None:
         """Invalidate the device carry (rebuilt lazily from the models)."""

@@ -22,7 +22,7 @@ is:
      (e.g. ``BatchedSimulatedExecutor2D``) runs every measuring job's
      distribution in one call;
   3. ONE stacked fold-in — all jobs' observations enter the device carry
-     via a single vectorized sorted insert (buffers donated off-CPU).
+     via a single vectorized sorted insert (buffers donated).
 
 Per-job results surface as the existing typed
 :class:`~repro.core.scheduler.Partition`, bit-identical — allocations AND
@@ -303,7 +303,6 @@ class FleetScheduler:
         sharding: Optional[str] = None,
         max_group_knots: int = 64,
         staleness_tol: Optional[float] = None,
-        compilation_cache_dir: Optional[str] = None,
         detector=None,
         reserve_knots: Optional[int] = None,
         quantize: float = 0.0,
@@ -349,11 +348,6 @@ class FleetScheduler:
         self._hier_cache: Dict[int, Hierarchy] = {}  # lane -> per-stack solver
         self._hier_stack_ref = None  # carry the cache was built against
         self.staleness_tol = float(staleness_tol) if staleness_tol is not None else None
-        self.compilation_cache_dir = compilation_cache_dir
-        if compilation_cache_dir is not None and backend == "jax":
-            from ..core.modelbank_jax import enable_compilation_cache
-
-            enable_compilation_cache(compilation_cache_dir)
         self.p = p
         self._backend = backend
         self.dtype = dtype

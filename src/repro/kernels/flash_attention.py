@@ -20,9 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 __all__ = ["flash_attention_pallas"]
 
 NEG_INF = -2.0e38
@@ -135,7 +132,7 @@ def flash_attention_pallas(
             pltpu.VMEM((bq, 1), jnp.float32),  # running sum
             pltpu.VMEM((bq, D), jnp.float32),  # output accumulator
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

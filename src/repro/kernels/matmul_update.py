@@ -21,9 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 __all__ = ["matmul_update_pallas"]
 
 
@@ -76,7 +73,7 @@ def matmul_update_pallas(
         out_shape=jax.ShapeDtypeStruct((M, N), c.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         input_output_aliases={0: 0},
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -17,9 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 __all__ = ["rglru_scan_pallas"]
 
 
@@ -70,7 +67,7 @@ def rglru_scan_pallas(
         out_specs=pl.BlockSpec((1, bs, bd), lambda bb, db, sb: (bb, sb, db)),
         out_shape=jax.ShapeDtypeStruct((B, S, D), log_a.dtype),
         scratch_shapes=[pltpu.VMEM((1, bd), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ..compile_cache import use_compile_cache
 from ..configs import ARCH_IDS, SHAPES, ShapeSpec, get_config, shape_applicable
 from ..models import encdec as ED
 from ..models import transformer as T
@@ -434,6 +435,7 @@ def main(argv=None) -> int:
     ap.add_argument("--resume", action="store_true",
                     help="skip cells whose output JSON already exists and is ok")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     archs = ARCH_IDS if args.arch == "all" else [args.arch]
     shapes = [s.name for s in SHAPES] if args.shape == "all" else [args.shape]

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..compile_cache import use_compile_cache
 from ..configs import ARCH_IDS, get_config, get_smoke_config
 from ..nn.params import init_tree
 from ..runtime.serve_loop import ReplicaDispatcher, ServeEngine
@@ -33,6 +34,7 @@ def main(argv=None):
     ap.add_argument("--replicas", type=int, default=0, help=">0: DFPA dispatch demo")
     ap.add_argument("--chunks", type=int, default=64)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if cfg.is_encdec:

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..compile_cache import use_compile_cache
 from ..configs import ARCH_IDS, get_config, get_smoke_config
 from ..checkpoint import CheckpointManager, load_checkpoint
 from ..core.scheduler import Scheduler
@@ -139,6 +140,7 @@ def main(argv=None):
     ap.add_argument("--units", type=int, default=16, help="microbatches per global step")
     ap.add_argument("--ckpt", default=None)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if args.groups <= 1:

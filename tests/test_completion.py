@@ -30,7 +30,6 @@ import pytest
 from _hyp import given, settings, st
 
 import jax
-from jax.experimental import enable_x64
 
 from repro.core import PiecewiseLinearFPM, Scheduler, SpeedStore
 from repro.core.modelbank import ModelBank
@@ -136,7 +135,7 @@ def test_flag_scaled_propagation():
 
 def test_flag_jax_mirrors_numpy_and_survives_fold_in():
     rng = np.random.default_rng(2)
-    with enable_x64():
+    with jax.enable_x64():
         good = _bank(_monotone_rows(rng, 5))
         bad = ModelBank.from_models(_spike_models())
         assert JaxModelBank.from_bank(good).is_monotone() == good.is_monotone()
@@ -167,7 +166,7 @@ def test_flag_jax_mirrors_numpy_and_survives_fold_in():
 
 def test_flag_stack_combination():
     rng = np.random.default_rng(3)
-    with enable_x64():
+    with jax.enable_x64():
         good = JaxModelBank.from_bank(
             _bank(_monotone_rows(rng, 4))
         )
@@ -224,7 +223,7 @@ def test_jax_auto_demotes_nonmonotone_to_exact(monkeypatch):
 
     monkeypatch.setattr(mbj, "_partition_units_jit", spy)
     rng = np.random.default_rng(5)
-    with enable_x64():
+    with jax.enable_x64():
         bad = JaxModelBank.from_bank(ModelBank.from_models(_spike_models()))
         d = bad.partition_units(37, min_units=1)
         assert int(np.asarray(d).sum()) == 37
@@ -244,7 +243,7 @@ def test_nonmonotone_auto_equals_forced_greedy():
         bad, 55, list(icaps), min_units=1, completion="greedy"
     )
     assert d_auto == d_greedy and t_auto == t_greedy
-    with enable_x64():
+    with jax.enable_x64():
         jb = JaxModelBank.from_bank(bad)
         d_jax = jb.partition_units(55, min_units=1)
         d_jax_g = jb.partition_units(55, min_units=1, completion="greedy")
@@ -271,7 +270,7 @@ def test_infeasible_raises_identically_both_modes(completion):
     rng = np.random.default_rng(6)
     bank = _bank(_monotone_rows(rng, 5))
     store = SpeedStore.from_bank(bank)
-    with enable_x64():
+    with jax.enable_x64():
         jb = JaxModelBank.from_bank(bank)
         for kw in _infeasible_variants(5, 200):
             with pytest.raises(ValueError):
@@ -292,7 +291,7 @@ def test_cap_below_min_units_raises_under_threshold():
     rng = np.random.default_rng(7)
     bank = _bank(_monotone_rows(rng, 4))
     store = SpeedStore.from_bank(bank)
-    with enable_x64():
+    with jax.enable_x64():
         jb = JaxModelBank.from_bank(bank)
         for completion in ("threshold", "greedy", "auto"):
             with pytest.raises(ValueError, match="min_units"):
@@ -311,7 +310,7 @@ def test_empty_model_positive_cap_raises_under_threshold():
     store = SpeedStore.from_bank(bank)
     with pytest.raises(ValueError):
         store.partition_units(10, completion="threshold")
-    with enable_x64():
+    with jax.enable_x64():
         with pytest.raises(ValueError):
             JaxModelBank.from_bank(bank).partition_units(10, completion="threshold")
 
@@ -323,7 +322,7 @@ def test_unknown_completion_mode_rejected_everywhere():
         _partition_units_bank(bank, 30, [30] * 3, min_units=0, completion="fast")
     with pytest.raises(ValueError, match="completion"):
         SpeedStore.from_bank(bank).partition_units(30, completion="fast")
-    with enable_x64():
+    with jax.enable_x64():
         with pytest.raises(ValueError, match="completion"):
             JaxModelBank.from_bank(bank).partition_units(30, completion="fast")
     with pytest.raises(ValueError, match="completion"):
@@ -422,7 +421,7 @@ def _check_completion_parity(case, *, with_jax=True):
     assert d_fast == d_exact
     if not with_jax:
         return
-    with enable_x64():
+    with jax.enable_x64():
         jb = JaxModelBank.from_bank(bank)
         d_jax_fast = jb.partition_units(n, caps, min_units=min_units)
         d_jax_exact = jb.partition_units(
@@ -513,7 +512,7 @@ def test_stacked_threshold_matches_per_column_exact():
     rng = np.random.default_rng(1003)
     q, p, n = 5, 6, 700
     cols = [_monotone_rows(rng, p, k_max=6) for _ in range(q)]
-    with enable_x64():
+    with jax.enable_x64():
         banks = [
             JaxModelBank.from_bank(_bank(c)) for c in cols
         ]
@@ -549,7 +548,7 @@ def test_stacked_with_one_adversarial_column_demotes_only_itself():
     p, n = 4, 300
     good = _monotone_rows(rng, p)
     bad = [[(10.0, 5.0), (20.0, 50.0)] for _ in range(p)]
-    with enable_x64():
+    with jax.enable_x64():
         banks = [
             JaxModelBank.from_bank(_bank(c))
             for c in (good, bad)
@@ -613,7 +612,7 @@ def test_scheduler_state_dict_round_trips_dtype():
         PiecewiseLinearFPM.from_points([(10.0, 5.0 + i), (100.0, 4.0 + i)])
         for i in range(4)
     ]
-    with enable_x64():
+    with jax.enable_x64():
         sched = Scheduler(
             SpeedStore.from_models(models, backend="jax", dtype=np.float32),
             n_units=120,
@@ -652,7 +651,7 @@ def test_float32_store_allocations_match_float64_at_p_10k():
     n = 100 * p
     bank = _bank(_serving_fleet(p, seed=p))
     assert bank.is_monotone()
-    with enable_x64():
+    with jax.enable_x64():
         s64 = SpeedStore.from_jax_bank(JaxModelBank.from_bank(bank))
         s32 = SpeedStore.from_jax_bank(
             JaxModelBank.from_bank(bank, dtype=np.float32)
@@ -672,7 +671,7 @@ def test_float32_store_construction_and_state_round_trip():
         PiecewiseLinearFPM.from_points([(10.0, 5.0 + i), (100.0, 4.0 + i)])
         for i in range(4)
     ]
-    with enable_x64():
+    with jax.enable_x64():
         s32 = SpeedStore.from_models(models, backend="jax", dtype=np.float32)
         assert str(s32.device_bank(snapshot=False).dtype) == "float32"
         d32 = s32.partition_units(200, min_units=1)

@@ -31,7 +31,6 @@ import pytest
 from _hyp import given, settings, st
 
 import jax
-from jax.experimental import enable_x64
 
 from repro.core import PiecewiseLinearFPM, Scheduler, SpeedStore
 from repro.core.energy import (
@@ -260,7 +259,7 @@ def _check_all(case):
 
 
 def test_energy_parity_smoke(rng):
-    with enable_x64():
+    with jax.enable_x64():
         for _ in range(25):
             _check_all(_random_case(rng))
 
@@ -268,7 +267,7 @@ def test_energy_parity_smoke(rng):
 @pytest.mark.slow
 def test_energy_parity_fuzz_lane():
     rng = np.random.default_rng(42)
-    with enable_x64():
+    with jax.enable_x64():
         for _ in range(200):
             _check_all(_random_case(rng))
 
@@ -312,7 +311,7 @@ def _hyp_cases(draw):
 @given(case=_hyp_cases())
 @settings(max_examples=200, deadline=None)
 def test_energy_parity_fuzz_hypothesis(case):
-    with enable_x64():
+    with jax.enable_x64():
         _check_all(case)
 
 

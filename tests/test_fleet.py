@@ -27,7 +27,6 @@ import pytest
 from _hyp import given, settings, st
 
 import jax
-from jax.experimental import enable_x64
 
 from repro.core import (
     BatchedSimulatedExecutor2D,
@@ -213,7 +212,7 @@ def _check_fleet_parity(case, backend):
 def test_fleet_parity_three_jobs_jax():
     rng = np.random.default_rng(100)
     case = _random_fleet_case(rng)
-    with enable_x64():
+    with jax.enable_x64():
         _check_fleet_parity(case, "jax")
 
 
@@ -250,7 +249,7 @@ def test_partition_grid_scalar_backend_still_works():
 def test_fleet_parity_smoke_fuzz_jax():
     """Tier-1 jax smoke: 6 random fleets through the full parity checker."""
     rng = np.random.default_rng(102)
-    with enable_x64():
+    with jax.enable_x64():
         for _ in range(6):
             _check_fleet_parity(_random_fleet_case(rng), "jax")
 
@@ -267,7 +266,7 @@ def test_fleet_parity_fuzz_jax_lane():
     """200 fuzzed fleets on the stacked device path (shapes kept small so
     the jit cache amortizes across cases)."""
     rng = np.random.default_rng(104)
-    with enable_x64():
+    with jax.enable_x64():
         for _ in range(200):
             case = _random_fleet_case(rng)
             _check_fleet_parity(case, "jax")
@@ -347,7 +346,7 @@ def test_admit_mid_flight_matches_independent():
             for s in specs
         ],
     )
-    with enable_x64():
+    with jax.enable_x64():
         indep = _independent_results(case, "jax")
         fleet = FleetScheduler(p, backend="jax")
         ex = BatchedSimulatedExecutor2D(
@@ -383,7 +382,7 @@ def test_retire_mid_flight_prefix_and_survivors():
             for j in range(q)
         ],
     )
-    with enable_x64():
+    with jax.enable_x64():
         indep = _independent_results(case, "jax")
         fleet = FleetScheduler(p, backend="jax")
         for j in range(q):
@@ -421,7 +420,7 @@ def test_resize_equals_warm_readmission():
     ex = BatchedSimulatedExecutor2D(
         time_fn_batch_2d=_batch_fn(base, knee), p=p, q=1, job_names=["a"]
     )
-    with enable_x64():
+    with jax.enable_x64():
         fleet = FleetScheduler(p, backend="jax")
         fleet.admit(JobSpec(name="a", n=60, eps=0.03, min_units=1, max_iter=4))
         fleet.run(ex)
@@ -452,7 +451,7 @@ def test_rebalance_drops_stale_result_and_reports_live_view():
     ex = BatchedSimulatedExecutor2D(
         time_fn_batch_2d=_batch_fn(base, knee), p=p, q=1, job_names=["a"]
     )
-    with enable_x64():
+    with jax.enable_x64():
         fleet = FleetScheduler(p, backend="jax")
         fleet.admit(JobSpec(name="a", n=60, eps=0.3, min_units=1, max_iter=6))
         fleet.run(ex)
@@ -495,7 +494,7 @@ def test_adversarial_job_demotes_only_its_own_lane(monkeypatch):
 
     monkeypatch.setattr(mbj, "_partition_units_jit", spy)
 
-    with enable_x64():
+    with jax.enable_x64():
         # independent references
         indep = []
         for j in range(q):
@@ -541,7 +540,7 @@ def test_mixed_caps_and_min_units_respected():
     p = 5
     base, knee = _knee_params(rng, 2, p)
     caps = [8, 40, 40, 40, 40]
-    with enable_x64():
+    with jax.enable_x64():
         fleet = FleetScheduler(p, backend="jax")
         fleet.admit(JobSpec(name="capped", n=60, eps=0.05, caps=caps, min_units=1))
         fleet.admit(JobSpec(name="free", n=95, eps=0.05, min_units=2))
@@ -597,7 +596,7 @@ def test_registry_roundtrip_reproduces_donor_allocations(tmp_path):
     ex = BatchedSimulatedExecutor2D(
         time_fn_batch_2d=_batch_fn(a, k), p=p, q=1, job_names=["donor"]
     )
-    with enable_x64():
+    with jax.enable_x64():
         reg = ProfileRegistry()
         donor = FleetScheduler(
             p, backend="jax", registry=reg, device_classes=CLASSES
@@ -639,7 +638,7 @@ def test_registry_missing_workload_starts_cold():
     a, k = _class_fns()
     reg = ProfileRegistry()
     reg.record("cpu", "other-workload", [(10.0, 5.0)])
-    with enable_x64():
+    with jax.enable_x64():
         fleet = FleetScheduler(
             4, backend="jax", registry=reg, device_classes=CLASSES
         )
@@ -752,7 +751,7 @@ def test_power_cap_binds_and_fits_budget(backend):
             fl.admit(JobSpec(str(j), n), models=sm, energy_models=em)
         return fl
 
-    with enable_x64():
+    with jax.enable_x64():
         free = build(None)
         ds0 = free.rebalance()
         e_free = sum(_fleet_round_energy(free, nm, d) for nm, d in ds0.items())
@@ -781,7 +780,7 @@ def test_power_cap_binds_and_fits_budget(backend):
 def test_power_cap_none_is_bit_identical():
     """power_cap=None must not perturb a single allocation (do-no-harm)."""
     p = 4
-    with enable_x64():
+    with jax.enable_x64():
         a = FleetScheduler(p, backend="jax")
         b = FleetScheduler(p, backend="jax", power_cap=None)
         sm, em = _energy_fixtures(p, seed=3)
@@ -795,7 +794,7 @@ def test_power_cap_unpriced_jobs_run_time_optimal():
     are excluded from the budget."""
     p = 4
     sm, em = _energy_fixtures(p, seed=7)
-    with enable_x64():
+    with jax.enable_x64():
         free = FleetScheduler(p, backend="jax")
         free.admit(JobSpec("u", 240), models=sm)
         want = free.rebalance()["u"]
@@ -809,7 +808,7 @@ def test_power_cap_infeasible_degrades_to_energy_optimal():
 
     p = 4
     sm, em = _energy_fixtures(p, seed=9)
-    with enable_x64():
+    with jax.enable_x64():
         fl = FleetScheduler(p, backend="numpy", power_cap=1e-9)
         fl.admit(JobSpec("t", 200), models=sm, energy_models=em)
         d = fl.rebalance()["t"]
@@ -880,7 +879,7 @@ def test_lane_buckets_bit_parity_and_zero_recompiles():
             fl.admit(JobSpec(f"j{j}", 150 + 40 * j), models=sm)
         return fl
 
-    with enable_x64():
+    with jax.enable_x64():
         plain, bucketed = mk(False), mk(True)
         assert plain.rebalance() == bucketed.rebalance()
         # pad 3 -> 4 lanes: the stacked carry is wider than the job list
@@ -928,7 +927,7 @@ def test_lane_buckets_full_autotune_parity():
         results = fleet.run(ex)
         return fleet, results
 
-    with enable_x64():
+    with jax.enable_x64():
         fa, ra = run(False)
         fb, rb = run(True)
     for j in range(q):
@@ -957,7 +956,7 @@ def test_replica_dispatcher_fleet_mode():
         return t
 
     disp = ReplicaDispatcher(replica_run, 4, eps=0.15)
-    with enable_x64():
+    with jax.enable_x64():
         results = disp.balance_fleet(
             {"chat": 48, "embed": 96}, backend="jax", min_units=1
         )

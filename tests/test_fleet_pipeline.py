@@ -29,7 +29,7 @@ import json
 import numpy as np
 import pytest
 
-from jax.experimental import enable_x64
+import jax
 
 from repro.core import (
     BatchedSimulatedExecutor2D,
@@ -168,7 +168,7 @@ def test_pipeline_validation():
 
 def test_depth0_bit_identical_to_sync_jax_smoke():
     rng = np.random.default_rng(900)
-    with enable_x64():
+    with jax.enable_x64():
         for _ in range(4):
             _check_depth0_identity(_random_fleet_case(rng), "jax")
 
@@ -189,7 +189,7 @@ def test_depth0_bit_identity_fuzz_numpy_lane():
 @pytest.mark.slow
 def test_depth0_bit_identity_fuzz_jax_lane():
     rng = np.random.default_rng(903)
-    with enable_x64():
+    with jax.enable_x64():
         for _ in range(200):
             _check_depth0_identity(_random_fleet_case(rng), "jax")
 
@@ -210,7 +210,7 @@ def test_sync_default_bit_identity_fuzz_lane():
 
 def test_bounded_staleness_jax_smoke():
     rng = np.random.default_rng(910)
-    with enable_x64():
+    with jax.enable_x64():
         for _ in range(4):
             _check_bounded_staleness(_converging_case(rng), "jax")
 
@@ -231,7 +231,7 @@ def test_bounded_staleness_fuzz_numpy_lane():
 @pytest.mark.slow
 def test_bounded_staleness_fuzz_jax_lane():
     rng = np.random.default_rng(913)
-    with enable_x64():
+    with jax.enable_x64():
         for _ in range(200):
             _check_bounded_staleness(_converging_case(rng), "jax")
 
@@ -245,7 +245,7 @@ def test_staleness_bound_never_exceeds_one_generation():
     case = _converging_case(rng)
     fleet = _mk_fleet(case, "jax", pipeline=True, pipeline_depth=1)
     ex = _mk_ex(case)
-    with enable_x64():
+    with jax.enable_x64():
         for _ in range(10):
             if not fleet.active_jobs:
                 break
@@ -289,7 +289,7 @@ def test_serving_rebalance_cycle_accepts_stale_read():
         return fl
 
     epoch = {"0": [0.2 * (i + 1) for i in range(p)]}
-    with enable_x64():
+    with jax.enable_x64():
         sync, pipe = build(False), build(True)
         assert sync.rebalance() == pipe.rebalance()  # no stale generation yet
         for fl in (sync, pipe):
@@ -318,7 +318,7 @@ def test_resize_after_convergence_accepts_stale_and_reconverges():
         q=q,
         job_names=[str(j) for j in range(q)],
     )
-    with enable_x64():
+    with jax.enable_x64():
         fleet = FleetScheduler(p, backend="jax", pipeline=True, pipeline_depth=1)
         for j in range(q):
             fleet.admit(
@@ -351,7 +351,7 @@ def test_every_fold_vs_partition_interleaving_reaches_sync_fixed_point():
     rng = np.random.default_rng(920)
     case = _converging_case(rng)
     R = 4
-    with enable_x64():
+    with jax.enable_x64():
         fs, rs = _run_case(case, "jax")
         for schedule in itertools.product([False, True], repeat=R):
             fleet = _mk_fleet(case, "jax", pipeline=True, pipeline_depth=1)
@@ -401,7 +401,7 @@ def test_pipeline_depth0_membership_changes_bit_identical_to_sync():
             job_names=[str(j) for j in range(q)],
         )
 
-    with enable_x64():
+    with jax.enable_x64():
         sync = FleetScheduler(p, backend="jax")
         ret_s, res_s = _membership_script(sync, mk_ex(), specs)
         pipe = FleetScheduler(p, backend="jax", pipeline=True, pipeline_depth=0)
@@ -441,7 +441,7 @@ def test_pipeline_depth1_membership_changes_prefix_parity():
             job_names=[str(j) for j in range(q)],
         )
 
-    with enable_x64():
+    with jax.enable_x64():
         sync = FleetScheduler(p, backend="jax")
         ret_s, res_s = _membership_script(sync, mk_ex(), specs)
         fleet = FleetScheduler(p, backend="jax", pipeline=True, pipeline_depth=1)
@@ -478,7 +478,7 @@ def test_state_dict_roundtrip_mid_flight(backend, pipeline):
     rng = np.random.default_rng(940)
     case = _converging_case(rng)
     kw = dict(pipeline=True, pipeline_depth=1) if pipeline else {}
-    with enable_x64():
+    with jax.enable_x64():
         donor = _mk_fleet(case, backend, **kw)
         ex = _mk_ex(case)
         for _ in range(3):
@@ -503,7 +503,7 @@ def test_state_dict_roundtrip_mid_flight(backend, pipeline):
 def test_state_dict_drains_pipeline():
     rng = np.random.default_rng(941)
     case = _converging_case(rng)
-    with enable_x64():
+    with jax.enable_x64():
         fleet = _mk_fleet(case, "jax", pipeline=True, pipeline_depth=1)
         ex = _mk_ex(case)
         fleet.step(ex)
@@ -557,7 +557,7 @@ def test_quantize_lane_buckets_composed_parity_and_dummy_lane_noop():
         results = fleet.run(ex)
         return fleet, results, snap
 
-    with enable_x64():
+    with jax.enable_x64():
         fa, ra, _ = run(False)
         fb, rb, snap = run(True)
         # padded stack: 4 lanes for 3 jobs, the 4th masked out
@@ -599,7 +599,7 @@ def test_quantize_lane_buckets_pipeline_composed():
             for j in range(q)
         ],
     )
-    with enable_x64():
+    with jax.enable_x64():
         fs, rs = _run_case(case, "jax", quantize=0.05)
         fp, rp = _run_case(
             case,
@@ -635,7 +635,7 @@ def test_pipeline_power_cap_reads_consistent_generation():
             fl.admit(JobSpec(str(j), n), models=sm, energy_models=em)
         return fl
 
-    with enable_x64():
+    with jax.enable_x64():
         sync, pipe = build(False), build(True)
         assert sync.rebalance() == pipe.rebalance()
         for fl in (sync, pipe):
@@ -667,7 +667,7 @@ def test_pipeline_hier_route_depth0_matches_sync():
         res = fs.run(_FleetExec(p), max_rounds=10)
         return fs, {k: (v.allocations, v.diagnostics["history"]) for k, v in res.items()}
 
-    with enable_x64():
+    with jax.enable_x64():
         _, sync = run()
         _, d0 = run(pipeline=True, pipeline_depth=0)
         _, d1 = run(pipeline=True, pipeline_depth=1)
@@ -687,7 +687,7 @@ def test_delayed_executor_preserves_times_and_fleet_parity():
     rng = np.random.default_rng(960)
     case = _converging_case(rng)
     lat = {str(j): 0.5 * j for j in range(case["q"])}
-    with enable_x64():
+    with jax.enable_x64():
         fs, rs = _run_case(case, "jax", pipeline=True, pipeline_depth=1)
         fleet = _mk_fleet(case, "jax", pipeline=True, pipeline_depth=1)
         wrapped = DelayedBatchedExecutor(inner=_mk_ex(case), lane_latency=lat, seed=7)
@@ -765,7 +765,7 @@ def test_balance_fleet_pipeline_threading_and_warm_toggle():
         return t
 
     tenants = {"chat": 48, "embed": 96}
-    with enable_x64():
+    with jax.enable_x64():
         sync = ReplicaDispatcher(replica_run, 4, eps=0.15)
         res_s = sync.balance_fleet(tenants, backend="jax", min_units=1)
         disp = ReplicaDispatcher(replica_run, 4, eps=0.15)

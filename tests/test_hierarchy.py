@@ -29,7 +29,6 @@ import numpy as np
 import pytest
 
 import jax
-from jax.experimental import enable_x64
 
 from repro.core import ModelBank, Policy, Scheduler, SpeedStore
 from repro.core.hierarchy import Hierarchy
@@ -117,7 +116,7 @@ def test_hier_jax_matches_numpy_smoke():
     """The jax lax.map block path returns exactly the numpy per-group loop
     (bit-identical on CPU under x64)."""
     rng = np.random.default_rng(202)
-    with enable_x64():
+    with jax.enable_x64():
         for _ in range(6):
             case = _random_case(rng, p_hi=20)
             d_np = _check_hier_vs_flat(case, backend="numpy")
@@ -132,7 +131,7 @@ def test_single_group_bit_identical_all_backends():
     bank = _random_bank(rng, 23)
     n = 907
     d_flat, _ = _partition_units_bank(bank, n, [n] * bank.p, min_units=0)
-    with enable_x64():
+    with jax.enable_x64():
         for backend, sharding in [("numpy", None), ("jax", None), ("jax", "shard_map")]:
             h = Hierarchy.from_bank(bank, [0] * bank.p, backend=backend, sharding=sharding)
             d = h.partition_units(n)
@@ -146,7 +145,7 @@ def test_shard_map_matches_unsharded():
     """shard_map over the host's devices returns exactly the single-program
     jax path, for group counts that do and don't divide the device count."""
     rng = np.random.default_rng(204)
-    with enable_x64():
+    with jax.enable_x64():
         for g in (1, 2, 5, len(jax.devices()) + 1):
             p = 6 * g
             groups = (np.arange(p) % g).tolist()
@@ -194,7 +193,7 @@ def test_nonmonotone_group_demotes_only_itself():
     ]
     assert sub_monos == [True, False]
     n = 1500
-    with enable_x64():
+    with jax.enable_x64():
         d_auto_np = Hierarchy.from_bank(bank, groups).partition_units(n)
         d_greedy_np = Hierarchy.from_bank(bank, groups).partition_units(
             n, completion="greedy"
@@ -303,7 +302,7 @@ def _run_fleet(p, **kw):
 @pytest.mark.parametrize("backend", ["numpy", "jax"])
 def test_fleet_hier_single_group_matches_flat(backend):
     p = 24
-    with enable_x64():
+    with jax.enable_x64():
         flat = _run_fleet(p, backend=backend)
         hier = _run_fleet(p, backend=backend, groups=[0] * p)
     if BIT_EXACT:
@@ -315,7 +314,7 @@ def test_fleet_hier_single_group_matches_flat(backend):
 def test_fleet_hier_multigroup_converges():
     p = 24
     groups = [i % 3 for i in range(p)]
-    with enable_x64():
+    with jax.enable_x64():
         flat = _run_fleet(p, backend="jax")
         hier = _run_fleet(p, backend="jax", groups=groups)
     for k in flat:
@@ -351,7 +350,7 @@ def test_hier_fuzz_jax_lane():
     """100 fuzzed cases through the jax block path (shapes vary, so the jit
     cache churns more than the flat stacked tests — kept to 100)."""
     rng = np.random.default_rng(211)
-    with enable_x64():
+    with jax.enable_x64():
         for _ in range(100):
             case = _random_case(rng, p_hi=24)
             d_np = _check_hier_vs_flat(case, backend="numpy")
@@ -363,7 +362,7 @@ def test_hier_fuzz_jax_lane():
 @pytest.mark.slow
 def test_hier_shard_map_fuzz_lane():
     rng = np.random.default_rng(212)
-    with enable_x64():
+    with jax.enable_x64():
         for _ in range(40):
             case = _random_case(rng, p_hi=24)
             d_jx = _check_hier_vs_flat(case, backend="jax")

@@ -30,7 +30,7 @@ Parity contract asserted here:
   * the stacked ``[q, p, k]`` bank partitions every column exactly as the
     per-column calls do.
 
-The jax lane runs under ``jax.experimental.enable_x64`` so its float ops are
+The jax lane runs under ``jax.enable_x64`` so its float ops are
 IEEE-double identical to numpy's — that is what makes bit-equality a fair
 assertion (float32 would differ by a unit here and there).
 """
@@ -41,7 +41,6 @@ import pytest
 from _hyp import given, settings, st
 
 import jax
-from jax.experimental import enable_x64
 
 from repro.core import (
     BatchedSimulatedExecutor,
@@ -160,7 +159,7 @@ def _check_query_parity(case, rng):
 
     s_np, t_np = bank.speed(x), bank.time(x)
     a_np = bank.alloc_at_time(t, caps)
-    with enable_x64():
+    with jax.enable_x64():
         jb = _jax_bank(bank)
         s_jx = np.asarray(jb.speed(x))
         t_jx = np.asarray(jb.time(x))
@@ -225,7 +224,7 @@ def _check_partition_parity(case):
 
     d_scalar = partition_units(models, n, caps, min_units=min_units, vectorize=False)
     d_bank = partition_units(bank, n, caps, min_units=min_units)
-    with enable_x64():
+    with jax.enable_x64():
         d_jax = partition_units(_jax_bank(bank), n, caps, min_units=min_units, backend="jax")
 
     for d in (d_scalar, d_bank, d_jax):
@@ -252,7 +251,7 @@ def _check_partition_parity(case):
         d_greedy, _ = _partition_units_bank(
             bank, n, icaps, min_units=min_units, completion="greedy"
         )
-        with enable_x64():
+        with jax.enable_x64():
             d_thr_jax = _jax_bank(bank).partition_units(
                 n, caps, min_units=min_units, completion="threshold"
             )
@@ -306,7 +305,7 @@ def _check_infeasible_parity(case):
             (dict(backend="jax"), bank),
         ):
             with pytest.raises(ValueError):
-                with enable_x64():
+                with jax.enable_x64():
                     partition_units(src, kw["n"], kw["caps"],
                                     min_units=kw["min_units"], **path_kw)
 
@@ -360,7 +359,7 @@ def test_min_units_cap_shortfall_raises_on_all_paths():
         (bank, dict(backend="jax")),
     ):
         with pytest.raises(ValueError, match="min_units"):
-            with enable_x64():
+            with jax.enable_x64():
                 partition_units(src, 20, caps=[1, 20, 20, 20], min_units=2, **kw)
 
 
@@ -369,7 +368,7 @@ def test_empty_model_with_positive_cap_raises_on_bank_paths():
     bank = ModelBank.from_models(models)
     with pytest.raises(ValueError):
         partition_units(bank, 10)
-    with enable_x64():
+    with jax.enable_x64():
         with pytest.raises(ValueError):
             partition_units(_jax_bank(bank), 10, backend="jax")
 
@@ -382,7 +381,7 @@ def test_empty_model_with_positive_cap_raises_on_bank_paths():
 def _check_fold_in_parity(rng):
     p = int(rng.integers(1, 8))
     models = [PiecewiseLinearFPM() for _ in range(p)]
-    with enable_x64():
+    with jax.enable_x64():
         jb = JaxModelBank.empty(p, k=2)
         for _ in range(int(rng.integers(1, 14))):
             x = np.round(rng.uniform(1, 25, p))  # small ints -> many duplicates
@@ -429,7 +428,7 @@ def test_stacked_bank_matches_per_column():
         ]
         for _ in range(q)
     ]
-    with enable_x64():
+    with jax.enable_x64():
         banks = [JaxModelBank.from_models(ms) for ms in col_models]
         stacked = JaxModelBank.stack(banks)
         d_all = stacked.partition_units(n, min_units=1)
@@ -448,7 +447,7 @@ def test_stacked_bank_rejected_by_flat_partition_api():
     """The flat List[int] API can't express [q, p] results; it must say so
     instead of crashing with an opaque conversion TypeError."""
     ms = [PiecewiseLinearFPM.from_points([(10.0, 5.0), (100.0, 4.0)])] * 3
-    with enable_x64():
+    with jax.enable_x64():
         stacked = JaxModelBank.stack([JaxModelBank.from_models(ms)] * 2)
         with pytest.raises(ValueError, match="stacked"):
             partition_units(stacked, 30, backend="jax")
@@ -478,7 +477,7 @@ def test_bank_repartition_2d_matches_numpy_backend():
                 nb = np.full(p * q, float(w))
                 fpms[i][j].add_point(float(r), float(g_batch(mb, nb)[i * q + j]) / w)
             fpm_width[i][j] = w
-    with enable_x64():
+    with jax.enable_x64():
         rows_jax = bank_repartition_2d(fpms, fpm_width, widths, M, backend="jax")
     rows_np = bank_repartition_2d(fpms, fpm_width, widths, M, backend="numpy")
     assert rows_jax == rows_np
@@ -520,7 +519,7 @@ def test_dfpa_jax_backend_reproduces_numpy_history():
         )
 
     r_np = dfpa(mk(), n, eps=0.025, min_units=1)
-    with enable_x64():
+    with jax.enable_x64():
         r_jx = dfpa(mk(), n, eps=0.025, min_units=1, backend="jax")
     assert r_np.d == r_jx.d
     assert r_np.iterations == r_jx.iterations
@@ -531,7 +530,7 @@ def test_dfpa_jax_backend_reproduces_numpy_history():
 def test_balance_controller_jax_backend_matches_numpy():
     def run(backend):
         if backend == "jax":
-            with enable_x64():
+            with jax.enable_x64():
                 return _run(backend)
         return _run(backend)
 
@@ -550,7 +549,7 @@ def test_balance_controller_jax_backend_matches_numpy():
     assert trace_np == trace_jx
     assert ctl_np.rebalances == ctl_jx.rebalances
     # the device snapshot agrees with the scalar models it mirrors
-    with enable_x64():
+    with jax.enable_x64():
         snap = ctl_jx.device_bank().to_bank()
     ref = ctl_jx.bank()
     for i in range(4):
@@ -561,7 +560,7 @@ def test_steady_state_carry_width_stays_bounded():
     """Regression: duplicate-x folds (a converged controller re-observing
     the same distribution every step) must not inflate the host-tracked
     count bound into endless padded-width doublings and jit recompiles."""
-    with enable_x64():
+    with jax.enable_x64():
         ctl = BalanceController(n_units=64, num_groups=4, eps=0.05, backend="jax")
         speeds = [4.0, 4.0, 4.0, 2.0]
         for _ in range(60):
@@ -578,7 +577,7 @@ def test_dfpa_scalar_executor_jax_backend_small():
     ex = SimulatedExecutor(
         time_fns=[lambda x: x / 100.0, lambda x: x / 40.0, lambda x: x / 10.0]
     )
-    with enable_x64():
+    with jax.enable_x64():
         res = dfpa(ex, 300, eps=0.02, min_units=1, backend="jax")
     assert sum(res.d) == 300
     assert res.converged

@@ -26,6 +26,8 @@ import warnings
 import numpy as np
 import pytest
 
+import jax
+
 from repro import obs
 from repro.core import PiecewiseLinearFPM
 from repro.fleet import FleetScheduler, JobSpec, ProfileRegistry
@@ -33,8 +35,6 @@ from repro.obs.chrometrace import to_chrome_trace
 from repro.obs.report import MetricsSnapshot
 from repro.runtime.serve_loop import ReplicaDispatcher
 from repro.runtime.straggler import StragglerAction, StragglerDetector
-
-from test_fleet import enable_x64  # noqa: F401  (the x64 scope helper)
 
 
 # ---------------------------------------------------------------------------
@@ -241,7 +241,7 @@ def test_deterministic_serving_replay_has_zero_speculative_misses():
     previous carry speculatively, but serving tenants (admitted with
     learned models, never measuring) keep empty seen sets — every
     speculative read must be CONSUMED, none discarded."""
-    with enable_x64():
+    with jax.enable_x64():
         fleet, base = _mk_serving_fleet(backend="jax", pipeline=True,
                                         pipeline_depth=1)
         _serve_epochs(fleet, base, epochs=6)

@@ -14,6 +14,8 @@ import warnings
 import numpy as np
 import pytest
 
+import jax
+
 from repro.core import (
     AnalyticModel,
     HCL_SPECS,
@@ -64,9 +66,7 @@ def test_speedstore_partition_matches_legacy(backend):
     models = _fleet(6, seed=3)
     n, caps, mu = 1234, [400, 500, 300, 600, 200, 400], 2
     if backend == "jax":
-        from jax.experimental import enable_x64
-
-        with enable_x64():
+        with jax.enable_x64():
             store = SpeedStore.from_models(models, backend="jax")
             assert store.backend == "jax"
             got = store.partition_units(n, caps, min_units=mu)
@@ -217,8 +217,6 @@ def test_policy_grid2d_matches_legacy():
 
 
 def test_grid2d_jax_backend_matches_numpy():
-    from jax.experimental import enable_x64
-
     p, q, M = 3, 2, 128
     rng = np.random.default_rng(5)
     widths = [40, 44]
@@ -232,7 +230,7 @@ def test_grid2d_jax_backend_matches_numpy():
     rows_np = Scheduler(policy=Policy.GRID2D).repartition_grid(
         fpms, fpm_width, widths, M
     )
-    with enable_x64():
+    with jax.enable_x64():
         rows_jax = Scheduler(policy=Policy.GRID2D, backend="jax").repartition_grid(
             fpms, fpm_width, widths, M
         )
@@ -392,9 +390,7 @@ def test_state_roundtrip_bit_identical_next_round(backend):
     bit-identical to the uninterrupted run."""
     ctx = contextlib.nullcontext()
     if backend == "jax":
-        from jax.experimental import enable_x64
-
-        ctx = enable_x64()
+        ctx = jax.enable_x64()
     speeds = [4.0, 3.0, 1.5, 2.0]
     with ctx:
         sched = Scheduler(
