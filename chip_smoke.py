@@ -55,8 +55,8 @@ from repro.core import (  # noqa: E402
     SimulatedExecutor,
     SpeedStore,
 )
+from repro import obs  # noqa: E402
 from repro.fleet import FleetScheduler, JobSpec  # noqa: E402
-from repro.fleet.scheduler import _jit_cache_sizes  # noqa: E402
 from repro.kernels import ops, ref  # noqa: E402
 
 
@@ -258,12 +258,13 @@ def phase_fleet(q=16, p=1_000, *, eps=0.05, max_iter=10, rebalance_rounds=3,
         # Serving: tenant loads drift; one stacked program per round, read
         # from the newest carry (drain() completes a pipeline's last fold).
         fleet.drain()
-        cache0 = _jit_cache_sizes()
         rebalance_s, serve_diff = [], 0
+        tel = obs.Telemetry()  # its jax.compile spans name what compiled
         for r in range(rebalance_rounds):
             loads = {nm: ns[j] + (r * 29 + j * 13) % 97 for j, nm in enumerate(names)}
             t0 = time.perf_counter()
-            ds = fleet.rebalance(loads)
+            with obs.use(tel):
+                ds = fleet.rebalance(loads)
             rebalance_s.append(time.perf_counter() - t0)
             for nm, load in loads.items():
                 _check_alloc(ds[nm], load, 1, [load] * p, f"fleet {mode} rebalance {nm}")
@@ -273,7 +274,7 @@ def phase_fleet(q=16, p=1_000, *, eps=0.05, max_iter=10, rebalance_rounds=3,
             serve_diff = max(serve_diff, _max_diff(ds[nm], want))
         check(serve_diff <= 1, f"fleet {mode}: rebalance differs from numpy by "
                                f"{serve_diff} units")
-        cache1 = _jit_cache_sizes()
+        compiled = [e.attrs["fun_name"] for e in tel.spans(obs.COMPILE_SPAN)]
         out[mode] = report(
             f"fleet_{mode}", q=q, p=p, eps=eps, rounds=fleet.rounds,
             device_dispatches=fleet.device_dispatches,
@@ -282,8 +283,10 @@ def phase_fleet(q=16, p=1_000, *, eps=0.05, max_iter=10, rebalance_rounds=3,
             run_s=run_s, compile_s=cw.stats["compile_s"],
             cache_hits=cw.stats["cache_hits"], cache_misses=cw.stats["cache_misses"],
             rebalance_s=rebalance_s,
-            rebalance_new_compiles={"partition": cache1[0] - cache0[0],
-                                    "fold": cache1[1] - cache0[1]},
+            rebalance_new_compiles={
+                "partition": sum("_partition_units_impl" in f for f in compiled),
+                "fold": sum("_fold_in_impl" in f for f in compiled),
+            },
         )
     return out
 

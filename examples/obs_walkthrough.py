@@ -12,8 +12,8 @@ claim, so PR 10 gave the stack a telemetry substrate.  This walkthrough:
      post-incident JSON naming the offender and its strike evidence.
 
 Everything is off by default: with no sink installed every instrumentation
-site short-circuits on a no-op (the BENCH_fleet ``obs_overhead`` gate holds
-even the ENABLED cost under 2% of a serving epoch).
+site short-circuits on a no-op (the enabled cost is measured in PERF.md,
+section 5).
 
     PYTHONPATH=src python examples/obs_walkthrough.py
 """

@@ -347,11 +347,11 @@ class Hierarchy:
         tel = _obs_active()
         rec = tel is not None and tel.enabled
         if rec:
-            t0 = tel.clock()
+            tok = tel.begin("hier.outer", groups=self.g, n=n)
         shares, t_outer, gbank = self._outer_shares(n, caps_arr, min_units)
         if rec:
-            t1 = tel.clock()
-            tel.span_at("hier.outer", t0, t1, groups=self.g, n=n)
+            tel.end(tok)
+            tok = tel.begin("hier.inner", groups=self.g, backend=self.backend)
 
         if self.backend == "jax":
             d_full = self._inner_jax(shares, caps_arr, min_units, completion, max_steps)
@@ -369,8 +369,7 @@ class Hierarchy:
                 )
                 d_full[idx] = d_sub
         if rec:
-            tel.span_at("hier.inner", t1, tel.clock(),
-                        groups=self.g, backend=self.backend)
+            tel.end(tok)
         out = [int(v) for v in d_full]
         assert sum(out) == n
         return (out, float(t_outer)) if with_t else out
@@ -417,7 +416,7 @@ class Hierarchy:
         floors = np.array(
             [min_units * len(idx) for idx in self.members], dtype=np.int64
         )
-        xs_list, t_outer = _partition_continuous_bank(
+        xs_list, t_outer, _ = _partition_continuous_bank(
             gbank,
             float(n),
             [min(float(c), float(n)) for c in gcaps_i],

@@ -8,10 +8,10 @@ ops.  The ``t*`` search of the geometric partitioner runs entirely on device:
   * exponential bracketing as a ``lax.while_loop`` (masked per batch element,
     so a stacked ``[q, p, k]`` bank bisects every column's ``t*``
     simultaneously);
-  * bisection as a fixed-iteration ``lax.fori_loop`` carrying ``(lo, hi,
-    done)`` — the ``done`` flag reproduces the numpy path's early-exit
-    semantics exactly, so the two backends take bit-identical branch
-    sequences;
+  * bisection as a ``lax.while_loop`` carrying ``(lo, hi, done)`` — the
+    ``done`` flag reproduces the numpy path's early-exit semantics exactly,
+    so the two backends take bit-identical branch sequences (and the same
+    number of trips, which the partition program returns);
   * the greedy integer completion as a masked lexicographic-argmin pass
     (smallest ``(time(d+1), -frac_remainder, index)``) instead of a Python
     heap — one ``O(p)`` argmin per leftover unit, with only the winning
@@ -61,7 +61,21 @@ from jax import lax
 
 from .modelbank import ModelBank
 
-__all__ = ["JaxModelBank", "fetch_partition"]
+try:  # telemetry is optional: the bank runs identically without repro.obs
+    from ..obs.telemetry import active as _obs_active
+except ImportError:  # pragma: no cover - obs layer absent
+    def _obs_active():
+        return None
+
+__all__ = ["JaxModelBank", "fetch_partition", "TRIP_LOOPS"]
+
+# The partition program's loops, in the order of its trips vector (see
+# ``_partition_units_impl``); telemetry counts them as
+# ``partition.trips.<loop>``.
+TRIP_LOOPS = (
+    "doubling", "bisection", "takeback",
+    "threshold_doubling", "threshold_bisection", "greedy",
+)
 
 
 # ---------------------------------------------------------------------------
@@ -288,7 +302,7 @@ def _partition_continuous_jit(xs, ss, counts, caps, n, rel_tol, max_steps):
         hi = jnp.where(_need(hi), hi * 2.0, hi)
         return hi, i + 1
 
-    hi, _ = lax.while_loop(dbl_cond, dbl_body, (hi, jnp.asarray(0, jnp.int32)))
+    hi, n_dbl = lax.while_loop(dbl_cond, dbl_body, (hi, jnp.asarray(0, jnp.int32)))
 
     # Bisection with early exit replicated via `done` (set AFTER the update,
     # exactly like the numpy loop's break).  A while_loop, not a fori_loop:
@@ -318,7 +332,7 @@ def _partition_continuous_jit(xs, ss, counts, caps, n, rel_tol, max_steps):
         done2 = done | (hi2 - lo2 <= rel_tol * hi2)
         return lo2, hi2, done2, i + 1
 
-    lo, hi, done, _ = lax.while_loop(
+    lo, hi, done, n_bis = lax.while_loop(
         bis_cond, bis_body, (lo, hi, done, jnp.asarray(0, jnp.int32))
     )
     t_star = hi
@@ -328,7 +342,7 @@ def _partition_continuous_jit(xs, ss, counts, caps, n, rel_tol, max_steps):
     excess = total - n
     scaled = alloc - (excess[..., None] * (alloc / total[..., None]))
     alloc = jnp.where(((total > zero) & (excess > zero))[..., None], scaled, alloc)
-    return alloc, t_star
+    return alloc, t_star, jnp.stack([n_dbl, n_bis])
 
 
 # ---------------------------------------------------------------------------
@@ -379,7 +393,7 @@ def _threshold_prefill(
         hi = jnp.where(_need(hi), hi * 2.0, hi)
         return hi, i + 1
 
-    hi, _ = lax.while_loop(dbl_cond, dbl_body, (hi, jnp.asarray(0, jnp.int32)))
+    hi, n_dbl = lax.while_loop(dbl_cond, dbl_body, (hi, jnp.asarray(0, jnp.int32)))
 
     # Same early-exit while_loop as the continuous bisection: inactive (or
     # converged) lanes freeze, and the loop stops when all have.
@@ -400,13 +414,13 @@ def _threshold_prefill(
         done2 = done | (hi2 - lo2 <= rel_tol * hi2)
         return lo2, hi2, done2, i + 1
 
-    lo, hi, done, _ = lax.while_loop(
+    lo, hi, done, n_bis = lax.while_loop(
         bis_cond, bis_body, (lo, hi, done, jnp.asarray(0, jnp.int32))
     )
     c_lo, g_lo = count(lo)
     d = jnp.where(active[..., None], g_lo, d0)
     leftover2 = jnp.where(active, leftover - c_lo, leftover)
-    return d, leftover2
+    return d, leftover2, jnp.stack([n_dbl, n_bis])
 
 
 def _complete_greedy_one(xs, ss, counts, caps_i, d, rem, leftover):
@@ -424,11 +438,11 @@ def _complete_greedy_one(xs, ss, counts, caps_i, d, rem, leftover):
     key0 = jnp.where((d + 1) <= caps_i, _time(xs, ss, counts, (d + 1).astype(dt)), jnp.inf)
 
     def cond(carry):
-        _, leftover, _, _ = carry
+        _, leftover, _, _, _ = carry
         return leftover > 0
 
     def body(carry):
-        d, leftover, key, ok = carry
+        d, leftover, key, ok, trips = carry
         i0 = jnp.argmin(key)  # first index of the minimum
         m1 = key[i0]
         feasible = jnp.isfinite(m1)
@@ -448,12 +462,12 @@ def _complete_greedy_one(xs, ss, counts, caps_i, d, rem, leftover):
         new_key = jnp.where((d2[i] + 1) <= caps_i[i], t_new, jnp.inf)
         key2 = key.at[i].set(jnp.where(feasible, new_key, key[i]))
         leftover2 = jnp.where(feasible, leftover - 1, 0)
-        return d2, leftover2, key2, ok & feasible
+        return d2, leftover2, key2, ok & feasible, trips + 1
 
-    d, _, _, ok = lax.while_loop(
-        cond, body, (d, leftover, key0, jnp.asarray(True))
+    d, _, _, ok, trips = lax.while_loop(
+        cond, body, (d, leftover, key0, jnp.asarray(True), jnp.asarray(0, jnp.int32))
     )
-    return d, ok
+    return d, ok, trips
 
 
 def _partition_units_impl(
@@ -468,12 +482,17 @@ def _partition_units_impl(
     # same device program.  This plain impl is also called per group block
     # inside ``_hier_inner_map``'s ``lax.map`` (and under ``shard_map``), so
     # it must stay jit-free; ``_partition_units_jit`` below is the jitted
-    # entry point with identical semantics.
+    # entry point with identical semantics.  Besides ``(d, ok, t_star)`` it
+    # returns the trips of each of its loops (``TRIP_LOOPS`` order, int32;
+    # greedy trips summed over the lanes of a stacked bank, 0 for a loop a
+    # static branch skipped).
     dt = xs.dtype
     it = caps_i.dtype
     n_f = jnp.asarray(n, dt)
     caps_f = jnp.minimum(caps_i.astype(dt), n_f[..., None])  # continuous clip
-    alloc, t_star = _partition_continuous_jit(xs, ss, counts, caps_f, n_f, rel_tol, max_steps)
+    alloc, t_star, trips_cont = _partition_continuous_jit(
+        xs, ss, counts, caps_f, n_f, rel_tol, max_steps
+    )
 
     d = jnp.maximum(min_units, jnp.floor(alloc).astype(it))
     d = jnp.minimum(d, caps_i)
@@ -500,14 +519,16 @@ def _partition_units_impl(
         return d, leftover + take.astype(it), kk + 1
 
     kk0 = jnp.zeros(leftover.shape, it)
-    d, leftover, _ = lax.while_loop(tb_cond, tb_body, (d, leftover, kk0))
+    d, leftover, kk = lax.while_loop(tb_cond, tb_body, (d, leftover, kk0))
+    trips_tb = jnp.max(kk).astype(jnp.int32)  # every lane steps each trip
 
     # -- threshold-count bulk grant (static branch: skipped entirely when no
     #    lane is monotone) — collapses all but the boundary-tied units into
     #    one more bisection; fast_mask routes it per lane.
     rem = alloc - jnp.floor(alloc)
+    trips_thr = jnp.zeros(2, jnp.int32)
     if completion_fast:
-        d, leftover = _threshold_prefill(
+        d, leftover, trips_thr = _threshold_prefill(
             xs, ss, counts, caps_i, d, leftover, t_star, rel_tol, max_steps,
             fast_mask,
         )
@@ -519,7 +540,7 @@ def _partition_units_impl(
     if batch:
         b = int(np.prod(batch))
         p_dim, k_dim = xs.shape[-2], xs.shape[-1]
-        d, ok = jax.vmap(_complete_greedy_one)(
+        d, ok, trips_gr = jax.vmap(_complete_greedy_one)(
             xs.reshape(b, p_dim, k_dim),
             ss.reshape(b, p_dim, k_dim),
             counts.reshape(b, p_dim),
@@ -530,9 +551,11 @@ def _partition_units_impl(
         )
         d = d.reshape(*batch, p_dim)
         ok = ok.reshape(batch)
+        trips_gr = trips_gr.sum(dtype=jnp.int32)
     else:
-        d, ok = _complete_greedy_one(xs, ss, counts, caps_i, d, rem, leftover)
-    return d, ok, t_star
+        d, ok, trips_gr = _complete_greedy_one(xs, ss, counts, caps_i, d, rem, leftover)
+    trips = jnp.concatenate([trips_cont, trips_tb[None], trips_thr, trips_gr[None]])
+    return d, ok, t_star, trips
 
 
 _partition_units_jit = partial(
@@ -572,7 +595,7 @@ def _hier_inner_map(
             xs, ss, counts, caps_i, n, min_units,
             jnp.asarray(rel_tol, xs.dtype), max_steps, fast_mask,
             completion_fast=completion_fast,
-        )
+        )[:3]
 
     def body(args):
         xs_g, ss_g, counts_g, caps_g, n_g, mu_g, fm_g = args
@@ -580,7 +603,7 @@ def _hier_inner_map(
             xs_g, ss_g, counts_g, caps_g, n_g, mu_g,
             jnp.asarray(rel_tol, xs_g.dtype), max_steps, fm_g,
             completion_fast=completion_fast,
-        )
+        )[:3]
 
     return lax.map(body, (xs, ss, counts, caps_i, n, min_units, fast_mask))
 
@@ -972,7 +995,7 @@ class JaxModelBank:
             jnp.asarray(n, self.dtype),
             jnp.asarray(rel_tol, self.dtype),
             max_steps,
-        )
+        )[:2]
 
     def partition_units(
         self, n, caps=None, *, min_units=0, max_steps: int = 200,
@@ -1063,7 +1086,7 @@ class JaxModelBank:
         self._check_feasible(caps_host.astype(np.float64), n)
         # min_units broadcast to row shape [..., p]: the kernel takes per-row
         # floors (uniform here; genuinely per-row on the hierarchical path).
-        d, ok, t_star = _partition_units_jit(
+        args = (
             self.xs, self.ss, self.counts,
             jnp.asarray(caps_host, idtype),
             jnp.asarray(n_host),
@@ -1071,15 +1094,28 @@ class JaxModelBank:
             jnp.asarray(1e-12, self.dtype),
             max_steps,
             jnp.asarray(lanes_host),
-            completion_fast=fast,
         )
         if defer:
+            d, ok, t_star, _ = _partition_units_jit(*args, completion_fast=fast)
             return (d, ok, t_star) if with_t else (d, ok)
-        if not bool(np.all(np.asarray(ok))):
+        # the wait: dispatch until the program's results are on the host
+        tel = _obs_active()
+        rec = tel is not None and tel.enabled
+        if rec:
+            tok = tel.begin("modelbank.partition.wait")
+        d, ok, t_star, trips = _partition_units_jit(*args, completion_fast=fast)
+        if rec:
+            trips.copy_to_host_async()  # on the host with d: no extra round trip
+        ok_host = bool(np.all(np.asarray(ok)))
+        d_host = np.asarray(d)
+        t_host = np.asarray(t_star) if with_t else None
+        if rec:
+            tel.end(tok)
+            for loop, v in zip(TRIP_LOOPS, np.asarray(trips).tolist()):
+                tel.counter(f"partition.trips.{loop}", v)
+        if not ok_host:
             raise ValueError("caps infeasible during integer completion")
-        if with_t:
-            return np.asarray(d), np.asarray(t_star)
-        return np.asarray(d)
+        return (d_host, t_host) if with_t else d_host
 
     # -- device-resident observation fold-in ---------------------------------
 
@@ -1095,6 +1131,11 @@ class JaxModelBank:
         generation keeps serving an in-flight repartition while the new one
         folds).  The returned bank is tagged one :attr:`generation` newer
         either way."""
+        # the host side of a fold: uploads, width check, kernel dispatch
+        tel = _obs_active()
+        rec = tel is not None and tel.enabled
+        if rec:
+            tok = tel.begin("modelbank.fold_in")
         x = jnp.broadcast_to(jnp.asarray(x, self.dtype), self.counts.shape)
         s = jnp.broadcast_to(jnp.asarray(s, self.dtype), self.counts.shape)
         # valid is host data in every caller (DFPA / BalanceController build
@@ -1122,6 +1163,8 @@ class JaxModelBank:
                 xs, ss = self._padded_to(k)
         kernel = _fold_in_jit if donate else _fold_in_nodonate_jit
         nxs, nss, ncounts = kernel(xs, ss, self.counts, x, s, valid)
+        if rec:
+            tel.end(tok)
         return JaxModelBank(
             xs=nxs, ss=nss, counts=ncounts, max_count=min(bound + 1, k),
             generation=self.generation + 1,

@@ -66,11 +66,6 @@ __all__ = [
 
 Models = Union[Sequence[SpeedModel], ModelBank]
 
-# Iteration count of the most recent host-side t* bisection (scalar or bank
-# kernel) — a telemetry tap read by SpeedStore.partition after a host solve.
-# The jax kernel runs its fixed-trip loop on device and does not report here.
-_LAST_BISECTION_STEPS: int = 0
-
 
 # ---------------------------------------------------------------------------
 # Internal kernels — the single implementation behind SpeedStore and the
@@ -99,12 +94,13 @@ def _continuous_scalar(
     *,
     rel_tol: float = 1e-12,
     max_steps: int = 200,
-) -> Tuple[List[float], float]:
+) -> Tuple[List[float], float, int]:
+    """``(allocations, t*, bisection steps)``."""
     p = len(models)
     if p == 0:
         raise ValueError("no processors")
     if n <= 0:
-        return [0.0] * p, 0.0
+        return [0.0] * p, 0.0, 0
     caps = _prep_continuous_caps(p, n, caps)
     return _partition_continuous_scalar(models, n, caps, rel_tol=rel_tol, max_steps=max_steps)
 
@@ -116,12 +112,13 @@ def _continuous_bank(
     *,
     rel_tol: float = 1e-12,
     max_steps: int = 200,
-) -> Tuple[List[float], float]:
+) -> Tuple[List[float], float, int]:
+    """``(allocations, t*, bisection steps)``."""
     p = len(bank)
     if p == 0:
         raise ValueError("no processors")
     if n <= 0:
-        return [0.0] * p, 0.0
+        return [0.0] * p, 0.0, 0
     caps = _prep_continuous_caps(p, n, caps)
     return _partition_continuous_bank(bank, n, caps, rel_tol=rel_tol, max_steps=max_steps)
 
@@ -152,7 +149,7 @@ def _partition_continuous_scalar(
     *,
     rel_tol: float,
     max_steps: int,
-) -> Tuple[List[float], float]:
+) -> Tuple[List[float], float, int]:
     """The seed per-model Python loop (one ``alloc_at_time`` call per model per
     bisection step) — kept as the fallback for non-piecewise models and as the
     benchmark baseline."""
@@ -177,8 +174,6 @@ def _partition_continuous_scalar(
             lo = mid
         if hi - lo <= rel_tol * hi:
             break
-    global _LAST_BISECTION_STEPS
-    _LAST_BISECTION_STEPS = steps
     t_star = hi
     xs = [m.alloc_at_time(t_star, c) for m, c in zip(models, caps)]
     total = sum(xs)
@@ -188,7 +183,7 @@ def _partition_continuous_scalar(
         excess = total - n
         if excess > 0:
             xs = [x - excess * (x / total) for x in xs]
-    return xs, t_star
+    return xs, t_star, steps
 
 
 def _partition_continuous_bank(
@@ -198,8 +193,9 @@ def _partition_continuous_bank(
     *,
     rel_tol: float,
     max_steps: int,
-) -> Tuple[List[float], float]:
-    """Bank path: the same bisection, one array op per step."""
+) -> Tuple[List[float], float, int]:
+    """Bank path: the same bisection, one array op per step; also returns
+    the bisection's steps."""
     caps_arr = np.asarray(caps, dtype=np.float64)
     active = caps_arr > 0.0
     if np.any(active & (bank.counts == 0)):
@@ -225,8 +221,6 @@ def _partition_continuous_bank(
             lo = mid
         if hi - lo <= rel_tol * hi:
             break
-    global _LAST_BISECTION_STEPS
-    _LAST_BISECTION_STEPS = steps
     t_star = hi
     xs = bank.alloc_at_time(t_star, caps_arr)
     total = float(xs.sum())
@@ -234,15 +228,18 @@ def _partition_continuous_bank(
         excess = total - n
         if excess > 0:
             xs = xs - excess * (xs / total)
-    return list(map(float, xs)), t_star
+    return list(map(float, xs)), t_star, steps
 
 
 def _partition_units_scalar(
-    models: Sequence[SpeedModel], n: int, icaps: List[int], *, min_units: int
+    models: Sequence[SpeedModel], n: int, icaps: List[int], *, min_units: int,
+    with_steps: bool = False,
 ) -> Tuple[List[int], float]:
+    """``(allocations, t*)``, plus the bisection's steps with
+    ``with_steps``."""
     p = len(models)
     fcaps = [float(c) for c in icaps]
-    xs, t_star = _continuous_scalar(models, float(n), fcaps)
+    xs, t_star, steps = _continuous_scalar(models, float(n), fcaps)
     d = [max(min_units, int(math.floor(x))) for x in xs]
     d = [min(di, ci) for di, ci in zip(d, icaps)]
     leftover = n - sum(d)
@@ -272,7 +269,7 @@ def _partition_units_scalar(
             raise ValueError("caps infeasible during integer completion")
         d[best_i] += 1
     assert sum(d) == n
-    return d, t_star
+    return (d, t_star, steps) if with_steps else (d, t_star)
 
 
 def _threshold_prefill_bank(
@@ -343,9 +340,10 @@ def _threshold_prefill_bank(
 
 def _partition_units_bank(
     bank: ModelBank, n: int, icaps: List[int], *, min_units: int,
-    completion: str = "auto",
+    completion: str = "auto", with_steps: bool = False,
 ) -> Tuple[List[int], float]:
-    """Vectorized floor + integer completion.
+    """Vectorized floor + integer completion: ``(allocations, t*)``, plus
+    the continuous bisection's steps with ``with_steps``.
 
     ``completion`` selects how the leftover units are placed (see the
     "completion modes" section in ``modelbank.py``): ``"greedy"`` is the
@@ -362,7 +360,7 @@ def _partition_units_bank(
         raise ValueError(f"unknown completion mode {completion!r}")
     p = bank.p
     caps_arr = np.asarray(icaps, dtype=np.int64)
-    xs_list, t_star = _continuous_bank(bank, float(n), [float(c) for c in icaps])
+    xs_list, t_star, steps = _continuous_bank(bank, float(n), [float(c) for c in icaps])
     xs = np.asarray(xs_list, dtype=np.float64)
     d = np.maximum(min_units, np.floor(xs).astype(np.int64))
     d = np.minimum(d, caps_arr)
@@ -409,7 +407,8 @@ def _partition_units_bank(
             if d[i] + 1 <= caps_arr[i]:
                 heapq.heappush(heap, (bank.time_one(i, float(d[i] + 1)), negrem, i))
     assert int(d.sum()) == n
-    return [int(v) for v in d], t_star
+    out = [int(v) for v in d]
+    return (out, t_star, steps) if with_steps else (out, t_star)
 
 
 # ---------------------------------------------------------------------------

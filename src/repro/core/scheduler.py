@@ -393,13 +393,14 @@ class Scheduler:
             if persist_caps:
                 self.caps = list(caps)
         mu = self.min_units if min_units is None else int(min_units)
+        if self.groups is not None and (objective != "time" or energy_cap is not None):
+            raise ValueError("hierarchical scheduler: objective='time' only")
         tel = _obs_active()
         rec = tel is not None and tel.enabled
         if rec:
-            t0 = tel.clock()
+            tok = tel.begin("scheduler.partition", n=n, objective=objective,
+                            hier=self.groups is not None)
         if self.groups is not None:
-            if objective != "time" or energy_cap is not None:
-                raise ValueError("hierarchical scheduler: objective='time' only")
             d, t_star = self._hier_partition(n, caps_now, mu)
         else:
             d, t_star = self.store.partition(
@@ -408,9 +409,7 @@ class Scheduler:
                 objective=objective, energy_cap=energy_cap,
             )
         if rec:
-            tel.span_at("scheduler.partition", t0, tel.clock(),
-                        n=n, objective=objective,
-                        hier=self.groups is not None)
+            tel.end(tok)
         self.d = list(d)
         return self._flat_result(d, t_star, eps=self.eps if eps is None else eps)
 
@@ -481,6 +480,12 @@ class Scheduler:
         if self.n_units is None:
             raise ValueError("observe() needs n_units (construct with n_units=...)")
         self.steps_observed += 1
+        # the round's root span: every span below reaches it through parent
+        tel = _obs_active()
+        rec = tel is not None and tel.enabled
+        if rec:
+            root = tel.begin("scheduler.observe", step=self.steps_observed)
+            tok = tel.begin("scheduler.ema")
         speeds = [1.0] * self.num_groups
         valid = [False] * self.num_groups
         for i, (di, ti) in enumerate(zip(self.d, times)):
@@ -491,24 +496,25 @@ class Scheduler:
             ema = ti if ema is None else (1 - self.smooth) * ema + self.smooth * ti
             self._ema[key] = ema
             speeds[i], valid[i] = di / ema, True
+        if rec:
+            tel.end(tok)
         self.store.fold_in([float(di) for di in self.d], speeds, valid)
-        tel = _obs_active()
-        if tel is not None and tel.enabled:
-            tel.counter("scheduler.observe")
-        if imbalance(times) <= self.eps:  # zero-allocation groups are ignored
-            return False
-        if self.groups is not None:
-            new_d, _ = self._hier_partition(self.n_units, self.caps, self.min_units)
-        else:
-            new_d = self.store.partition_units(
-                self.n_units, self.caps, min_units=self.min_units,
-                completion=self._completion_for(self.store),
-            )
-        if new_d == self.d:
-            return False
-        self.d = new_d
-        self.rebalances += 1
-        return True
+        changed = False
+        if imbalance(times) > self.eps:  # zero-allocation groups are ignored
+            if self.groups is not None:
+                new_d, _ = self._hier_partition(self.n_units, self.caps, self.min_units)
+            else:
+                new_d = self.store.partition_units(
+                    self.n_units, self.caps, min_units=self.min_units,
+                    completion=self._completion_for(self.store),
+                )
+            if new_d != self.d:
+                self.d = new_d
+                self.rebalances += 1
+                changed = True
+        if rec:
+            tel.end(root, changed=changed)
+        return changed
 
     # -- the DFPA measurement loop (previously core/dfpa.py) ------------------
 
@@ -566,10 +572,15 @@ class Scheduler:
         probes_left = probe_budget
         tel = _obs_active()
         rec = tel is not None and tel.enabled
-        t_tune = tel.clock() if rec else 0.0
+        if rec:
+            tune = tel.begin("scheduler.autotune", n=n)
 
         def measure(d: List[int]) -> List[float]:
+            if rec:
+                tok = tel.begin("scheduler.measure")
             times = executor.run(d)
+            if rec:
+                tel.end(tok)
             history.append((list(d), list(times)))
             seen[tuple(d)] = list(times)
             darr = [float(di) for di in d]
@@ -593,6 +604,10 @@ class Scheduler:
             d = repartition()
         else:
             d = _even(n, p)
+        # each measured round (measure, fold, partition) has a root span
+        # identified by its iteration
+        if rec:
+            rnd = tel.begin("scheduler.tune_round", iteration=1)
         times = measure(d)
         it = 1
 
@@ -603,10 +618,10 @@ class Scheduler:
             self.d = list(d)
             self.eps = eps
             if rec:
-                tel.span_at("scheduler.autotune", t_tune, tel.clock(),
-                            n=n, iterations=it, converged=bool(converged),
-                            imbalance=float(imb),
-                            probes_used=probe_budget - probes_left)
+                tel.end(rnd)
+                tel.end(tune, iterations=it, converged=bool(converged),
+                        imbalance=float(imb),
+                        probes_used=probe_budget - probes_left)
             return Partition(
                 allocations=list(d),
                 t_star=None,
@@ -649,6 +664,9 @@ class Scheduler:
                     return finish(best_d, best_t, it, best_imb <= eps, best_imb)
                 probes_left -= 1
                 d_new = probe
+            if rec:
+                tel.end(rnd)
+                rnd = tel.begin("scheduler.tune_round", iteration=it + 1)
             d = d_new
             times = measure(d)
             it += 1

@@ -445,6 +445,11 @@ class SpeedStore:
         backend, into the device carry — one vectorized sorted insert instead
         of a host rebuild.  Rows with ``valid[i] == False`` are untouched.
         Mutates the store in place and returns it."""
+        tel = _obs_active()
+        rec = tel is not None and tel.enabled
+        if rec:
+            tok = tel.begin("speedstore.fold_in")
+            host = tel.begin("speedstore.fold_host")
         self._ensure_models()
         xs = [float(v) for v in np.broadcast_to(np.asarray(x, dtype=np.float64), (self.p,))]
         ss = [float(v) for v in np.broadcast_to(np.asarray(s, dtype=np.float64), (self.p,))]
@@ -456,13 +461,13 @@ class SpeedStore:
         for i, (xi, si, ok) in enumerate(zip(xs, ss, vv)):
             if ok:
                 self._models[i].add_point(xi, si)
+        if rec:
+            tel.end(host)
         if self.backend == "jax":
             self._jbank = self._carry().fold_in(xs, ss, vv)
         self.fold_generation += 1
-        tel = _obs_active()
-        if tel is not None and tel.enabled:
-            tel.counter("speedstore.fold_in")
-            tel.gauge("speedstore.fold_generation", self.fold_generation)
+        if rec:
+            tel.end(tok)
         return self
 
     # -- the energy sub-store (core/energy.py) -------------------------------
@@ -585,8 +590,10 @@ class SpeedStore:
             )
             return [float(v) for v in xs], float(t_star)
         if self.backend == "numpy":
-            return _continuous_bank(self.bank(), float(n), caps, rel_tol=rel_tol, max_steps=max_steps)
-        return _continuous_scalar(self.models, float(n), caps, rel_tol=rel_tol, max_steps=max_steps)
+            solve = _continuous_bank(self.bank(), float(n), caps, rel_tol=rel_tol, max_steps=max_steps)
+        else:
+            solve = _continuous_scalar(self.models, float(n), caps, rel_tol=rel_tol, max_steps=max_steps)
+        return solve[:2]
 
     def partition_units(
         self, n: int, caps=None, *, min_units: int = 0, completion: str = "auto",
@@ -644,42 +651,36 @@ class SpeedStore:
             return [int(v) for v in front.allocations[idx]], float(front.times[idx])
         p = self.p
         icaps = _prep_unit_caps(p, n, caps, min_units)
+        if self.backend == "scalar" and completion == "threshold":
+            raise ValueError(
+                "the scalar backend has no threshold completion; use a banked "
+                "backend or completion='auto'/'greedy'"
+            )
         tel = _obs_active()
         rec = tel is not None and tel.enabled
         if rec:
-            t0 = tel.clock()
+            tok = tel.begin("speedstore.partition", n=int(n), backend=self.backend)
         if self.backend == "jax":
+            # JaxModelBank.partition_units counts the device loops' trips
             d, t_star = self._carry().partition_units(
                 n, icaps, min_units=min_units, with_t=True, completion=completion
             )
-            if rec:
-                # the jax bisection runs its fixed-trip loop on device, so
-                # there is no host iteration count to report
-                tel.span_at("speedstore.partition", t0, tel.clock(),
-                            n=int(n), backend="jax")
-            return [int(v) for v in d], float(t_star)
-        if self.backend == "numpy":
-            out = _partition_units_bank(
-                self.bank(), n, icaps, min_units=min_units, completion=completion
-            )
+            out = [int(v) for v in d], float(t_star)
         else:
-            if completion == "threshold":
-                raise ValueError(
-                    "the scalar backend has no threshold completion; use a banked "
-                    "backend or completion='auto'/'greedy'"
+            if self.backend == "numpy":
+                d, t_star, steps = _partition_units_bank(
+                    self.bank(), n, icaps, min_units=min_units, completion=completion,
+                    with_steps=True,
                 )
-            out = _partition_units_scalar(
-                self.models, n, icaps, min_units=min_units
-            )
+            else:
+                d, t_star, steps = _partition_units_scalar(
+                    self.models, n, icaps, min_units=min_units, with_steps=True
+                )
+            out = d, t_star
+            if rec:
+                tel.counter("partition.trips.bisection", steps)
         if rec:
-            from . import partition as _partition_mod
-
-            tel.gauge(
-                "speedstore.bisection_steps",
-                _partition_mod._LAST_BISECTION_STEPS,
-            )
-            tel.span_at("speedstore.partition", t0, tel.clock(),
-                        n=int(n), backend=self.backend)
+            tel.end(tok)
         return out
 
     # -- derived metrics ------------------------------------------------------

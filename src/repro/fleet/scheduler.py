@@ -165,17 +165,6 @@ except ImportError:  # pragma: no cover - obs layer absent
         return None
 
 
-def _jit_cache_sizes() -> Tuple[int, int]:
-    """(partition, fold) jit cache sizes — the recompile telemetry signal
-    (a lane-bucket admit that stays in its bucket must not move these)."""
-    from ..core import modelbank_jax as mbj
-
-    return (
-        mbj._partition_units_jit._cache_size(),
-        mbj._fold_in_jit._cache_size(),
-    )
-
-
 __all__ = ["JobSpec", "FleetScheduler"]
 
 
@@ -480,15 +469,6 @@ class FleetScheduler:
         for k, v in self.stats().items():
             tel.gauge(f"fleet.{k}", v)
 
-    def _recompile_counters(self, tel, cs0: Tuple[int, int]) -> None:
-        """Emit jit-cache growth since ``cs0`` as recompile counters (a
-        lane-bucket admit that stays in its bucket must not move these)."""
-        cs1 = _jit_cache_sizes()
-        if cs1[0] > cs0[0]:
-            tel.counter("fleet.recompile.partition", cs1[0] - cs0[0])
-        if cs1[1] > cs0[1]:
-            tel.counter("fleet.recompile.fold", cs1[1] - cs0[1])
-
     def _count(self, name: str) -> None:
         """Bump a telemetry counter iff a sink is installed (hot-path safe:
         two attribute reads when disabled, no allocation)."""
@@ -730,8 +710,7 @@ class FleetScheduler:
         tel = _obs_active()
         rec = tel is not None and tel.enabled
         if rec:
-            t_round = tel.clock()
-            cs0 = _jit_cache_sizes() if self._backend == "jax" else None
+            round_tok = tel.begin("fleet.round", round=self.rounds + 1)
 
         # Phase 1: choose this round's distributions.  New jobs follow
         # autotune's initial rule (warm_start_d | warm repartition | even);
@@ -757,11 +736,10 @@ class FleetScheduler:
         # then the host-side seen-set / probe-escape logic per job.
         if to_repart:
             if rec:
-                t0 = tel.clock()
+                tok = tel.begin("fleet.partition", jobs=len(to_repart))
             new_ds = self._repartition(to_repart)
             if rec:
-                tel.span_at("fleet.partition", t0, tel.clock(),
-                            jobs=len(to_repart))
+                tel.end(tok)
             for job, d_new in zip(to_repart, new_ds):
                 if job.status == "running":
                     key = tuple(d_new)
@@ -798,11 +776,10 @@ class FleetScheduler:
             names = [job.spec.name for job in to_measure]
             D = np.asarray([job.pending_d for job in to_measure], dtype=np.int64)
             if rec:
-                t0 = tel.clock()
+                tok = tel.begin("fleet.measure", jobs=len(to_measure))
             T = np.asarray(executor.run_jobs(names, D), dtype=np.float64)
             if rec:
-                tel.span_at("fleet.measure", t0, tel.clock(),
-                            jobs=len(to_measure))
+                tel.end(tok)
             alpha = self._alpha if self._alpha is not None else getattr(executor, "alpha", 0.0)
             beta = self._beta if self._beta is not None else getattr(executor, "beta", 0.0)
 
@@ -816,10 +793,10 @@ class FleetScheduler:
             else:
                 Df, Tf = D.astype(np.float64), T
             if rec:
-                t0 = tel.clock()
+                tok = tel.begin("fleet.fold", jobs=len(to_measure))
             self._fold(to_measure, Df, Tf)
             if rec:
-                tel.span_at("fleet.fold", t0, tel.clock(), jobs=len(to_measure))
+                tel.end(tok)
             for k, job in enumerate(to_measure):
                 d = job.pending_d
                 times = [float(v) for v in T[k]]
@@ -856,11 +833,7 @@ class FleetScheduler:
             # fold and whatever host work the caller does between rounds
             self._predispatch_next()
         if rec:
-            if cs0 is not None:
-                self._recompile_counters(tel, cs0)
-            tel.span_at("fleet.round", t_round, tel.clock(),
-                        round=self.rounds, measured=len(to_measure),
-                        finished=len(finished))
+            tel.end(round_tok, measured=len(to_measure), finished=len(finished))
             self._stats_gauges(tel)
         return finished
 
@@ -902,8 +875,7 @@ class FleetScheduler:
         tel = _obs_active()
         rec = tel is not None and tel.enabled
         if rec:
-            t0 = tel.clock()
-            cs0 = _jit_cache_sizes() if self._backend == "jax" else None
+            tok = tel.begin("fleet.rebalance", jobs=len(targets))
         ds = self._repartition(targets)
         out = {}
         for job, d in zip(targets, ds):
@@ -918,9 +890,7 @@ class FleetScheduler:
             out[job.spec.name] = list(d)
         self.rounds += 1
         if rec:
-            if cs0 is not None:
-                self._recompile_counters(tel, cs0)
-            tel.span_at("fleet.rebalance", t0, tel.clock(), jobs=len(targets))
+            tel.end(tok)
             self._stats_gauges(tel)
         return out
 
@@ -985,7 +955,7 @@ class FleetScheduler:
         tel = _obs_active()
         rec = tel is not None and tel.enabled
         if rec:
-            t0 = tel.clock()
+            tok = tel.begin("fleet.observe", jobs=len(jobs))
         D = np.asarray(Ds, dtype=np.float64)
         T = np.asarray(Ts, dtype=np.float64)
         self._fold(jobs, D, T)
@@ -994,7 +964,7 @@ class FleetScheduler:
             job.invalidate()
         self.rounds += 1
         if rec:
-            tel.span_at("fleet.observe", t0, tel.clock(), jobs=len(jobs))
+            tel.end(tok)
             self._stats_gauges(tel)
         if self.pipeline:
             # overlap the in-flight fold with the NEXT epoch's stacked
@@ -1519,7 +1489,7 @@ class FleetScheduler:
         tel = _obs_active()
         rec = tel is not None and tel.enabled
         if rec:
-            t_cap = tel.clock()
+            cap_tok = tel.begin("fleet.power_cap", jobs=len(priced))
 
         def job_energy(job: _Job, d) -> float:
             e = job.ebank().time(np.asarray(d, dtype=np.float64))
@@ -1535,8 +1505,7 @@ class FleetScheduler:
         if sum(job_energy(job, ds[k]) for k, job in priced) <= self.power_cap:
             if rec:
                 tel.gauge("fleet.power_cap.theta", 1.0)
-                tel.span_at("fleet.power_cap", t_cap, tel.clock(),
-                            jobs=len(priced), feasible=True, capped=False)
+                tel.end(cap_tok, feasible=True, capped=False)
             return ds
 
         # Per-job anchors: the time-optimal makespan (theta=1) and the pure
@@ -1586,8 +1555,7 @@ class FleetScheduler:
                 theta_used = hi
         if rec:
             tel.gauge("fleet.power_cap.theta", float(theta_used))
-            tel.span_at("fleet.power_cap", t_cap, tel.clock(),
-                        jobs=len(priced), feasible=feasible, capped=True)
+            tel.end(cap_tok, feasible=feasible, capped=True)
         out = [list(d) for d in ds]
         for k, _ in priced:
             out[k] = [int(v) for v in d_hi[k]]

@@ -11,10 +11,9 @@ of trusting one benchmark's printed fraction.
 
 Everything is off by default — the process-global sink is a no-op and every
 instrumentation site in the stack guards itself with a cheap ``enabled``
-check, so an uninstrumented run is bit-identical and unmeasurably close in
-wall-clock (the ``obs_overhead`` gate in ``BENCH_fleet.json`` holds the
-ENABLED cost under 2% of a fleet round too).  Turn it on by installing a
-sink::
+check, so an uninstrumented run is bit-identical and makes no call into this
+package.  The enabled cost is measured in ``PERF.md`` (section 5) on the
+chip benchmark's cells.  Turn it on by installing a sink::
 
     from repro import obs
 
@@ -27,19 +26,38 @@ sink::
     with obs.use(obs.Telemetry()) as tel:
         fleet.step(executor)
 
+Spans nest: each records the span open when it began as its ``parent``, and
+each holds a ``jax.profiler.TraceAnnotation`` while open, so a profiler
+trace shows them on its host plane beside the device programs.
+
 What gets recorded (the instrumented layers):
 
-* ``Scheduler`` — ``scheduler.partition`` / ``scheduler.autotune`` spans
-  (iterations, convergence), ``scheduler.observe`` counters,
+* ``Scheduler`` — a flat round's root span ``scheduler.observe`` (attr
+  ``step``) with children ``scheduler.ema`` (the EMA loop),
+  ``speedstore.fold_in`` and, when it repartitions,
+  ``speedstore.partition``; ``scheduler.autotune`` (the session) with one
+  ``scheduler.tune_round`` root per measured round (attr ``iteration``)
+  holding ``scheduler.measure`` (the executor's run), ``speedstore.fold_in``
+  and ``speedstore.partition``; ``scheduler.partition`` spans and
   ``scheduler.reprofile`` events;
-* ``SpeedStore`` — ``speedstore.fold_in`` counters, the
-  ``speedstore.fold_generation`` gauge, ``speedstore.partition`` spans with
-  the host bisection's iteration count;
+* ``SpeedStore`` — ``speedstore.fold_in`` with children
+  ``speedstore.fold_host`` (list conversions, the scalar ``add_point``
+  loop) and, on the jax backend, ``modelbank.fold_in``;
+  ``speedstore.partition``, which on the host backends counts the
+  bisection's steps as ``partition.trips.bisection``;
+* ``JaxModelBank`` — ``modelbank.fold_in`` (uploads, width check, kernel
+  dispatch) and ``modelbank.partition.wait`` (dispatch of the partition
+  program until its results are on the host), after which the trips of each
+  of the program's loops are counted as ``partition.trips.<loop>``
+  (``doubling``, ``bisection``, ``takeback``, ``threshold_doubling``,
+  ``threshold_bisection``, ``greedy``);
+* JAX — every backend compile (or persistent-cache load) as a
+  ``jax.compile`` span (attr ``fun_name``) under the span open at the time;
 * ``FleetScheduler`` — the round lifecycle as spans (``fleet.round`` with
   nested ``fleet.partition`` / ``fleet.measure`` / ``fleet.fold``,
-  ``fleet.rebalance``, ``fleet.observe``), restack/predispatch counters,
-  speculation hit/miss/stale-read counters, the power-cap theta gauge,
-  lane-bucket recompile counters (jit ``_cache_size()`` deltas), and every
+  ``fleet.rebalance``, ``fleet.observe``, ``fleet.power_cap``),
+  restack/predispatch counters, speculation hit/miss/stale-read counters,
+  the power-cap theta gauge, and every
   :meth:`~repro.fleet.scheduler.FleetScheduler.stats` field as a
   ``fleet.*`` gauge each round;
 * ``Hierarchy`` — aggregation-cache hit/miss counters and outer/inner
@@ -75,6 +93,7 @@ See ``examples/obs_walkthrough.py`` for an end-to-end tour.
 from .chrometrace import export_chrome_trace, to_chrome_trace
 from .flightrec import FlightRecorder
 from .telemetry import (
+    COMPILE_SPAN,
     NOOP,
     Event,
     NoopTelemetry,
@@ -97,6 +116,7 @@ def __getattr__(name):
 
 
 __all__ = [
+    "COMPILE_SPAN",
     "Event",
     "Telemetry",
     "NoopTelemetry",

@@ -20,22 +20,26 @@ import sys
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
+from .telemetry import COMPILE_SPAN
+
 __all__ = ["MetricsSnapshot", "summarize", "main"]
 
 
 def _load_counters_gauges(payload: Dict[str, Any]) -> Tuple[Dict, Dict, List]:
     """(counters, gauges, span rows) from either artifact format.  Span rows
-    are ``(name, duration_seconds)``."""
-    spans: List[Tuple[str, float]] = []
+    are ``(name, duration_seconds, attrs)``."""
+    spans: List[Tuple[str, float, Dict[str, Any]]] = []
     if "traceEvents" in payload:
         repro = payload.get("repro", {})
         for ev in payload["traceEvents"]:
             if ev.get("ph") == "X":
-                spans.append((ev.get("name", "?"), float(ev.get("dur", 0.0)) / 1e6))
+                spans.append((ev.get("name", "?"), float(ev.get("dur", 0.0)) / 1e6,
+                              ev.get("args") or {}))
         return dict(repro.get("counters", {})), dict(repro.get("gauges", {})), spans
     for e in payload.get("events", []):
         if e.get("kind") == "span":
-            spans.append((e.get("name", "?"), float(e["t1"]) - float(e["t0"])))
+            spans.append((e.get("name", "?"), float(e["t1"]) - float(e["t0"]),
+                          e.get("attrs") or {}))
     return (
         dict(payload.get("counters", {})),
         dict(payload.get("gauges", {})),
@@ -76,8 +80,6 @@ class MetricsSnapshot:
         if snap.rounds and snap.device_dispatches is not None:
             snap.dispatches_per_round = snap.device_dispatches / snap.rounds
         snap.restacks = gauges.get("fleet.restacks")
-        snap.recompiles_partition = counters.get("fleet.recompile.partition", 0.0)
-        snap.recompiles_fold = counters.get("fleet.recompile.fold", 0.0)
         snap.predispatches = gauges.get("fleet.predispatches")
         snap.stale_reads = gauges.get("fleet.stale_reads")
         snap.speculative_misses = gauges.get("fleet.speculative_misses")
@@ -85,15 +87,23 @@ class MetricsSnapshot:
             tried = snap.stale_reads + snap.speculative_misses
             if tried > 0:
                 snap.speculation_hit_rate = snap.stale_reads / tried
-        snap.fold_ins = counters.get("speedstore.fold_in", 0.0)
         snap.overhead_frac = gauges.get("serve.rebalance_overhead_frac")
         snap.reaction_epochs = gauges.get("serve.reaction_epochs")
         snap.strikes = counters.get("straggler.strike", 0.0)
         snap.reprofiles = counters.get("straggler.reprofile", 0.0)
         snap.quarantines = counters.get("straggler.quarantine", 0.0)
-        for name, dur in spans:
+        for name, dur, attrs in spans:
             snap.span_totals[name] = snap.span_totals.get(name, 0.0) + dur
             snap.span_counts[name] = snap.span_counts.get(name, 0) + 1
+            if name == "speedstore.fold_in":
+                snap.fold_ins += 1
+            elif name == COMPILE_SPAN:
+                # compiles (and persistent-cache loads) by the program's name
+                fun = str(attrs.get("fun_name", ""))
+                if "_partition_units_impl" in fun:
+                    snap.recompiles_partition += 1
+                elif "_fold_in_impl" in fun:
+                    snap.recompiles_fold += 1
         return snap
 
     @classmethod

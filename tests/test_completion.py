@@ -705,7 +705,7 @@ def test_prefill_strict_bracket_leaves_boundary_remainder():
         caps = np.full(p, n, dtype=np.int64)
         from repro.core.partition import _continuous_bank
 
-        xs, t_star = _continuous_bank(bank, float(n), [float(n)] * p)
+        xs, t_star, _ = _continuous_bank(bank, float(n), [float(n)] * p)
         d0 = np.minimum(np.floor(np.asarray(xs)).astype(np.int64), caps)
         leftover = n - int(d0.sum())
         if leftover <= 0:

@@ -131,6 +131,7 @@ def test_install_active_use():
     assert obs.active() is obs.NOOP
     # NOOP swallows every call without recording
     obs.NOOP.span_at("x", 0.0, 1.0)
+    obs.NOOP.end(obs.NOOP.begin("x"))
     obs.NOOP.counter("x")
     obs.NOOP.gauge("x", 1.0)
     obs.NOOP.event("x")
@@ -175,7 +176,7 @@ class _CountingDisabledSink:
     def _bump(self, *a, **k):
         self.calls += 1
 
-    span = span_at = counter = gauge = event = _bump
+    span = span_at = counter = gauge = event = begin = end = _bump
     clock = staticmethod(lambda: 0.0)
 
 
@@ -427,3 +428,213 @@ def test_serving_log_t_wall_monotonic_from_injected_clock():
     a = disp.logs[0]
     b = type(a)(**{**a.__dict__, "t_wall": -1.0})
     assert a == b
+
+
+# ---------------------------------------------------------------------------
+# program spans of a flat round: parents, the profiler's host plane, compile
+# spans, the partition program's loop trips
+# ---------------------------------------------------------------------------
+
+
+class _TrueTimes:
+    """Executor whose times come from fixed linear speeds."""
+
+    def __init__(self, speeds):
+        self.speeds = list(speeds)
+        self.num_procs = len(speeds)
+
+    def run(self, d):
+        return [x / s if x > 0 else 0.0 for x, s in zip(d, self.speeds)]
+
+
+def _flat_session(p=24, n=6000, backend="jax"):
+    """A flat DFPA session: ``autotune`` to eps, then one served round
+    that repartitions.  Returns the scheduler."""
+    from repro.core import Policy, Scheduler, SpeedStore
+
+    speeds = np.random.default_rng(3).uniform(1.0, 5.0, p)
+    sched = Scheduler(SpeedStore.empty(p, backend=backend), policy=Policy.DFPA,
+                      eps=0.02, min_units=1)
+    sched.autotune(_TrueTimes(speeds), n, 0.02, max_iter=30, min_units=1)
+    shifted = speeds * np.where(np.arange(p) < p // 4, 0.5, 1.0)
+    sched.observe(_TrueTimes(shifted).run(sched.d))
+    return sched
+
+
+def _tree(tel):
+    spans = {e.id: e for e in tel.spans()}
+
+    def root_of(e):
+        while e.parent in spans:
+            e = spans[e.parent]
+        return e
+
+    return spans, root_of
+
+
+def _inside(child, parent):
+    return parent.t0 <= child.t0 and child.t1 <= parent.t1
+
+
+def test_flat_round_span_tree():
+    tel = obs.Telemetry()
+    with jax.enable_x64(), obs.use(tel):
+        _flat_session()
+    spans, root_of = _tree(tel)
+    by_name = {}
+    for e in spans.values():
+        by_name.setdefault(e.name, []).append(e)
+    for e in spans.values():  # every child lies inside its parent
+        if e.parent:
+            assert _inside(e, spans[e.parent]), (e.name, spans[e.parent].name)
+
+    # the served round: one root carrying its step
+    (obs_root,) = by_name["scheduler.observe"]
+    assert obs_root.parent == 0 and obs_root.attrs["step"] == 1
+    assert obs_root.attrs["changed"] is True
+    kids = sorted((e.t0, e.name) for e in spans.values() if e.parent == obs_root.id
+                  and e.name != obs.COMPILE_SPAN)
+    assert [n for _, n in kids] == ["scheduler.ema", "speedstore.fold_in",
+                                    "speedstore.partition"]
+    fold = next(e for e in spans.values()
+                if e.name == "speedstore.fold_in" and e.parent == obs_root.id)
+    assert sorted(e.name for e in spans.values() if e.parent == fold.id
+                  and e.name != obs.COMPILE_SPAN) == ["modelbank.fold_in",
+                                                      "speedstore.fold_host"]
+    part = next(e for e in spans.values()
+                if e.name == "speedstore.partition" and e.parent == obs_root.id)
+    assert [e.name for e in spans.values() if e.parent == part.id
+            and e.name != obs.COMPILE_SPAN] == ["modelbank.partition.wait"]
+
+    # the session: measured rounds, each a root identified by its iteration
+    (tune,) = by_name["scheduler.autotune"]
+    rounds = sorted(by_name["scheduler.tune_round"], key=lambda e: e.t0)
+    assert [r.attrs["iteration"] for r in rounds] == list(range(1, len(rounds) + 1))
+    assert tune.attrs["iterations"] == len(rounds) >= 2
+    assert all(r.parent == tune.id for r in rounds)
+    for r in rounds:
+        names = [e.name for e in sorted(spans.values(), key=lambda e: e.t0)
+                 if e.parent == r.id and e.name != obs.COMPILE_SPAN]
+        assert names[:2] == ["scheduler.measure", "speedstore.fold_in"]
+        assert set(names) <= {"scheduler.measure", "speedstore.fold_in",
+                              "speedstore.partition"}
+    # every span of a round reaches that round's root
+    for e in spans.values():
+        if e.name in ("speedstore.fold_host", "modelbank.fold_in",
+                      "modelbank.partition.wait", "scheduler.measure"):
+            assert root_of(e).name in ("scheduler.observe", "scheduler.autotune")
+            assert spans[e.parent].name != "scheduler.autotune"
+
+
+def test_flat_jax_path_disabled_sink_makes_zero_obs_calls():
+    stub = _CountingDisabledSink()
+    obs.install(stub)
+    try:
+        with jax.enable_x64():
+            _flat_session()
+    finally:
+        obs.uninstall()
+    assert stub.calls == 0
+
+
+def test_flat_path_bit_identical_with_telemetry():
+    with jax.enable_x64():
+        with obs.use(obs.Telemetry()):
+            on = _flat_session()
+        off = _flat_session()
+        bank_on = on.store.device_bank(snapshot=False)
+        bank_off = off.store.device_bank(snapshot=False)
+        assert on.d == off.d
+        for a, b in ((bank_on.xs, bank_off.xs), (bank_on.ss, bank_off.ss),
+                     (bank_on.counts, bank_off.counts)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_program_spans_on_the_profilers_host_plane(tmp_path):
+    with jax.enable_x64():
+        sched = _flat_session()
+        times = _TrueTimes([1.0] * sched.num_groups).run(sched.d)
+        with obs.use(obs.Telemetry()):
+            jax.profiler.start_trace(str(tmp_path))
+            try:
+                sched.observe(times)
+            finally:
+                jax.profiler.stop_trace()
+    (path,) = list(tmp_path.rglob("*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(str(path))
+    host = {e.name for plane in data.planes if plane.name == "/host:CPU"
+            for line in plane.lines for e in line.events}
+    assert {"scheduler.observe", "speedstore.fold_in", "modelbank.fold_in"} <= host
+
+
+def test_compile_span_names_function_and_enclosing_span(tmp_path):
+    from repro.core import SpeedStore
+
+    tel = obs.Telemetry()
+    with jax.enable_x64(), obs.use(tel):
+        store = SpeedStore.empty(37, backend="jax")  # a shape no other test folds
+        with tel.span("test.enclosing"):
+            store.fold_in(np.arange(1.0, 38.0), np.full(37, 2.0))
+    spans, _ = _tree(tel)
+    enclosing = next(e for e in spans.values() if e.name == "test.enclosing")
+    compiles = [e for e in spans.values() if e.name == obs.COMPILE_SPAN
+                and "_fold_in_impl" in e.attrs["fun_name"]]
+    assert compiles, sorted({e.attrs.get("fun_name") for e in spans.values()})
+    (fold,) = [e for e in spans.values() if e.name == "modelbank.fold_in"]
+    assert all(e.parent == fold.id for e in compiles)
+    assert spans[fold.parent].parent == enclosing.id  # speedstore.fold_in
+    # the report counts them by the program's name
+    path = tmp_path / "trace.json"
+    obs.export_chrome_trace(tel, str(path))
+    snap = MetricsSnapshot.from_file(str(path))
+    assert snap.recompiles_fold == len(compiles)
+    assert snap.fold_ins == 1
+
+
+def _monotone_bank(p=16, seed=5):
+    """A store of ``p`` increasing-speed piecewise models (monotone times)."""
+    rng = np.random.default_rng(seed)
+    return [
+        PiecewiseLinearFPM.from_points(
+            [(x, s * (1.0 + 0.1 * k)) for k, x in enumerate((1.0, 50.0, 400.0, 3000.0))]
+        )
+        for s in rng.uniform(1.0, 4.0, p)
+    ]
+
+
+def _trips(backend, completion, n=5000, min_units=1, models=None):
+    from repro.core import SpeedStore
+
+    store = SpeedStore.from_models(models or _monotone_bank(), backend=backend)
+    tel = obs.Telemetry()
+    with obs.use(tel):
+        store.partition(n, min_units=min_units, completion=completion)
+    return {k.split(".")[-1]: v for k, v in tel.counters.items()
+            if k.startswith("partition.trips.")}
+
+
+def test_partition_trip_counters_per_loop():
+    from repro.core.modelbank_jax import TRIP_LOOPS
+
+    with jax.enable_x64():
+        greedy = _trips("jax", "greedy")
+        fast = _trips("jax", "threshold")
+        # min_units far above the continuous share of the slow rows: the
+        # floor overshoots n and the take-back loop runs
+        slow = _monotone_bank() + [PiecewiseLinearFPM.from_points([(1.0, 1e-3), (1e4, 1e-3)])]
+        takeback = _trips("jax", "greedy", n=17 * 40, min_units=40, models=slow)
+    assert set(greedy) == set(fast) == set(TRIP_LOOPS)
+    assert greedy["bisection"] > 0 and greedy["greedy"] > 0
+    # the static branch: no threshold completion compiled in greedy mode
+    assert greedy["threshold_doubling"] == greedy["threshold_bisection"] == 0
+    assert fast["threshold_bisection"] > 0
+    assert fast["greedy"] < greedy["greedy"]  # the bulk grant leaves a few units
+    assert greedy["takeback"] == 0 and takeback["takeback"] > 0
+
+
+def test_numpy_bisection_counter_matches_jax_trips():
+    with jax.enable_x64():
+        jax_trips = _trips("jax", "greedy")
+    np_trips = _trips("numpy", "greedy")
+    assert set(np_trips) == {"bisection"}
+    assert np_trips["bisection"] == jax_trips["bisection"] > 0

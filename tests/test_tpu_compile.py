@@ -89,9 +89,10 @@ def test_partition_units_compiles(one_chip, no_persistent_cache, batch, p):
             *args, 200, _spec(batch, jnp.bool_, one_chip),
             completion_fast=True,
         ).compile()
-        d, ok, t_star = compiled.out_info
+        d, ok, t_star, trips = compiled.out_info
     assert d.shape == rows and d.dtype == np.int64
     assert ok.shape == batch and t_star.shape == batch
+    assert trips.shape == (len(mbj.TRIP_LOOPS),) and trips.dtype == np.int32
 
 
 @pytest.mark.parametrize("batch,p", BANKS)
