@@ -51,7 +51,7 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass
 from functools import partial
-from typing import List, Optional, Sequence, Tuple
+from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -113,35 +113,93 @@ def _time(xs, ss, counts, x):
     return jnp.where(x > zero, x / _speed(xs, ss, counts, x), zero)
 
 
-def _alloc_at_time(xs, ss, counts, t, caps):
-    """Mirror of ``ModelBank.alloc_at_time``; ``t`` has the batch shape
-    (scalar for a single bank, ``[q]`` for a stacked one)."""
-    dt = xs.dtype
+# ``alloc_at_time`` is evaluated on every doubling and bisection trip of both
+# solves, ~95 times a partition.  So everything in it that does not depend on
+# ``t`` (the edge gathers, the slope division, intercepts, cap clips and
+# masks) is built once per program into a table with processors on the last
+# axis, ``[..., k-1, p]``, lane-dense on the TPU; a trip then runs one fused
+# select-and-reduce over it.
+
+
+class _Segments(NamedTuple):
+    """The cap-free part of the table: ``[..., p]`` edges, ``[..., k-1, p]``
+    segments (``None`` for a one-knot bank)."""
+
+    nonempty: jnp.ndarray  # counts > 0
+    first_x: jnp.ndarray
+    first_s: jnp.ndarray
+    last_x: jnp.ndarray
+    last_s: jnp.ndarray
+    x0: Optional[jnp.ndarray]
+    x1: Optional[jnp.ndarray]
+    m: Optional[jnp.ndarray]  # slope (s1 - s0) / (x1 - x0)
+    c: Optional[jnp.ndarray]  # intercept s0 - m * x0
+    seg_ok: Optional[jnp.ndarray]  # segment inside the row's knots, x1 > x0
+
+
+class _Table(NamedTuple):
+    """A ``_Segments`` clipped to one solve's caps: every column
+    ``alloc_at_time`` reads except ``t``."""
+
+    segs: _Segments
+    caps: jnp.ndarray
+    first_cap: jnp.ndarray  # min(first_x, caps)
+    right_ok: jnp.ndarray  # the t-free half of the right-region test
+    live: jnp.ndarray  # caps > 0 and a non-empty row
+    x1c: Optional[jnp.ndarray]  # min(x1, caps)
+    valid: Optional[jnp.ndarray]
+
+
+def _segments(xs, ss, counts):
+    first_x, first_s, last_x, last_s = _edges(xs, ss, counts)
+    k_max = xs.shape[-1]
+    if k_max < 2:
+        return _Segments(counts > 0, first_x, first_s, last_x, last_s, *[None] * 5)
+    one = jnp.asarray(1.0, xs.dtype)
+    xt, st_ = jnp.swapaxes(xs, -1, -2), jnp.swapaxes(ss, -1, -2)  # [..., k, p]
+    x0, x1 = xt[..., :-1, :], xt[..., 1:, :]
+    s0, s1 = st_[..., :-1, :], st_[..., 1:, :]
+    seg = jnp.arange(k_max - 1)[:, None]
+    denom = jnp.where(x1 > x0, x1 - x0, one)
+    m = (s1 - s0) / denom
+    seg_ok = (seg < (counts - 1)[..., None, :]) & (x1 > x0)
+    return _Segments(
+        counts > 0, first_x, first_s, last_x, last_s, x0, x1, m, s0 - m * x0, seg_ok
+    )
+
+
+def _capped(segs: _Segments, caps) -> _Table:
+    zero = jnp.asarray(0.0, segs.first_x.dtype)
+    x1c = valid = None
+    if segs.m is not None:
+        capk = caps[..., None, :]
+        x1c = jnp.minimum(segs.x1, capk)
+        valid = segs.seg_ok & (segs.x0 < capk)
+    return _Table(
+        segs, caps, jnp.minimum(segs.first_x, caps),
+        (caps > segs.last_x) & segs.nonempty, (caps > zero) & segs.nonempty,
+        x1c, valid,
+    )
+
+
+def _alloc_from_table(tab: _Table, t):
+    """Mirror of ``ModelBank.alloc_at_time`` on a table; ``t`` has the batch
+    shape (scalar for a single bank, ``[q]`` for a stacked one)."""
+    segs = tab.segs
+    dt = segs.first_x.dtype
     zero, one = jnp.asarray(0.0, dt), jnp.asarray(1.0, dt)
     t = jnp.asarray(t, dt)
     tb = t[..., None]  # broadcast against [..., p]
-    first_x, first_s, last_x, last_s = _edges(xs, ss, counts)
 
     # Region [0, x_1]: constant speed ss[..., 0].
-    best = jnp.minimum(tb * first_s, jnp.minimum(first_x, caps))
+    best = jnp.minimum(tb * segs.first_s, tab.first_cap)
 
     # Interior segments, all at once (static branch on the padded width).
-    k_max = xs.shape[-1]
-    if k_max >= 2:
-        x0, x1 = xs[..., :-1], xs[..., 1:]
-        s0, s1 = ss[..., :-1], ss[..., 1:]
-        seg = jnp.arange(k_max - 1)
-        valid = (
-            (seg < (counts - 1)[..., None])
-            & (x0 < caps[..., None])
-            & (x1 > x0)
-        )
-        x1c = jnp.minimum(x1, caps[..., None])
-        denom = jnp.where(x1 > x0, x1 - x0, one)
-        m = (s1 - s0) / denom
-        tseg = tb[..., None]  # against [..., p, k-1]
-        a = one - tseg * m
-        b = tseg * (s0 - m * x0)
+    if segs.m is not None:
+        x0, x1c = segs.x0, tab.x1c
+        tseg = tb[..., None]  # against [..., k-1, p]
+        a = one - tseg * segs.m
+        b = tseg * segs.c
         ub = b / jnp.where(a != zero, a, one)
         cand = jnp.where(
             a > zero,
@@ -152,20 +210,20 @@ def _alloc_at_time(xs, ss, counts, t, caps):
                 jnp.where(x1c >= ub, x1c, zero),
             ),
         )
-        cand = jnp.where(valid, cand, zero)
-        best = jnp.maximum(best, cand.max(axis=-1))
+        cand = jnp.where(tab.valid, cand, zero)
+        best = jnp.maximum(best, cand.max(axis=-2))
 
     # Region [x_m, cap]: constant speed at the last observed point.
-    ub_r = tb * last_s
-    right = (caps > last_x) & (ub_r >= last_x) & (counts > 0)
-    best = jnp.maximum(best, jnp.where(right, jnp.minimum(ub_r, caps), zero))
+    ub_r = tb * segs.last_s
+    right = tab.right_ok & (ub_r >= segs.last_x)
+    best = jnp.maximum(best, jnp.where(right, jnp.minimum(ub_r, tab.caps), zero))
 
-    best = jnp.where((caps > zero) & (counts > 0), best, zero)
+    best = jnp.where(tab.live, best, zero)
     return jnp.where(tb > zero, best, zero)
 
 
-def _total_alloc(xs, ss, counts, t, caps):
-    return _alloc_at_time(xs, ss, counts, t, caps).sum(axis=-1)
+def _alloc_at_time(xs, ss, counts, t, caps):
+    return _alloc_from_table(_capped(_segments(xs, ss, counts), caps), t)
 
 
 @jax.jit
@@ -275,9 +333,9 @@ def _monotone_lanes_jit(xs, ss, counts):
 # ---------------------------------------------------------------------------
 
 
-@partial(jax.jit, static_argnames=("max_steps",))
-def _partition_continuous_jit(xs, ss, counts, caps, n, rel_tol, max_steps):
+def _partition_continuous(xs, ss, counts, segs, caps, n, rel_tol, max_steps):
     dt = xs.dtype
+    tab = _capped(segs, caps)  # once, before this solve's loops
     zero = jnp.asarray(0.0, dt)
     n = jnp.asarray(n, dt)
     rel_tol = jnp.asarray(rel_tol, dt)
@@ -291,7 +349,7 @@ def _partition_continuous_jit(xs, ss, counts, caps, n, rel_tol, max_steps):
     hi = jnp.maximum(hi, jnp.asarray(1e-9, dt))
 
     def _need(hi):
-        return _total_alloc(xs, ss, counts, hi, caps) < n
+        return _alloc_from_table(tab, hi).sum(axis=-1) < n
 
     def dbl_cond(carry):
         hi, i = carry
@@ -326,7 +384,7 @@ def _partition_continuous_jit(xs, ss, counts, caps, n, rel_tol, max_steps):
     def bis_body(carry):
         lo, hi, done, i = carry
         mid = 0.5 * (lo + hi)
-        ge = _total_alloc(xs, ss, counts, mid, caps) >= n
+        ge = _alloc_from_table(tab, mid).sum(axis=-1) >= n
         hi2 = jnp.where(~done & ge, mid, hi)
         lo2 = jnp.where(~done & ~ge, mid, lo)
         done2 = done | (hi2 - lo2 <= rel_tol * hi2)
@@ -337,12 +395,19 @@ def _partition_continuous_jit(xs, ss, counts, caps, n, rel_tol, max_steps):
     )
     t_star = hi
 
-    alloc = _alloc_at_time(xs, ss, counts, t_star, caps)
+    alloc = _alloc_from_table(tab, t_star)
     total = alloc.sum(axis=-1)
     excess = total - n
     scaled = alloc - (excess[..., None] * (alloc / total[..., None]))
     alloc = jnp.where(((total > zero) & (excess > zero))[..., None], scaled, alloc)
     return alloc, t_star, jnp.stack([n_dbl, n_bis])
+
+
+@partial(jax.jit, static_argnames=("max_steps",))
+def _partition_continuous_jit(xs, ss, counts, caps, n, rel_tol, max_steps):
+    return _partition_continuous(
+        xs, ss, counts, _segments(xs, ss, counts), caps, n, rel_tol, max_steps
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -352,7 +417,7 @@ def _partition_continuous_jit(xs, ss, counts, caps, n, rel_tol, max_steps):
 
 
 def _threshold_prefill(
-    xs, ss, counts, caps_i, d0, leftover, t_star, rel_tol, max_steps, fast_mask
+    segs, caps_i, d0, leftover, t_star, rel_tol, max_steps, fast_mask
 ):
     """Batched threshold-count bulk completion (monotone-time banks).
 
@@ -367,14 +432,14 @@ def _threshold_prefill(
     routing: a non-monotone column demotes only itself, in the same device
     program) — pass through untouched.
     """
-    dt = xs.dtype
+    dt = t_star.dtype
     it = d0.dtype
-    caps_f = caps_i.astype(dt)
+    tab = _capped(segs, caps_i.astype(dt))  # once, before this solve's loops
     base_total = d0.sum(axis=-1)
     active = (leftover > 0) & fast_mask
 
     def count(t):
-        a = _alloc_at_time(xs, ss, counts, t, caps_f)
+        a = _alloc_from_table(tab, t)
         g = jnp.clip(jnp.floor(a).astype(it), d0, caps_i)
         return g.sum(axis=-1) - base_total, g
 
@@ -490,8 +555,9 @@ def _partition_units_impl(
     it = caps_i.dtype
     n_f = jnp.asarray(n, dt)
     caps_f = jnp.minimum(caps_i.astype(dt), n_f[..., None])  # continuous clip
-    alloc, t_star, trips_cont = _partition_continuous_jit(
-        xs, ss, counts, caps_f, n_f, rel_tol, max_steps
+    segs = _segments(xs, ss, counts)  # once per program, read by every trip
+    alloc, t_star, trips_cont = _partition_continuous(
+        xs, ss, counts, segs, caps_f, n_f, rel_tol, max_steps
     )
 
     d = jnp.maximum(min_units, jnp.floor(alloc).astype(it))
@@ -529,8 +595,7 @@ def _partition_units_impl(
     trips_thr = jnp.zeros(2, jnp.int32)
     if completion_fast:
         d, leftover, trips_thr = _threshold_prefill(
-            xs, ss, counts, caps_i, d, leftover, t_star, rel_tol, max_steps,
-            fast_mask,
+            segs, caps_i, d, leftover, t_star, rel_tol, max_steps, fast_mask,
         )
 
     # -- greedy completion (see _complete_greedy_one); stacked banks flatten
