@@ -80,8 +80,8 @@ the members' shapes.  ``core/hierarchy.py`` then solves the outer ``t*``
 bisection over the ``[g, k_g]`` group bank — O(g k_g) instead of O(p k) —
 and scatters each group's integer share to an inner per-group partition on
 the group's own ``[p_g, k]`` sub-bank: per-group host solves on numpy,
-one ``lax.map`` program over cache-resident ``[g, p_max, k]`` blocks on
-jax (``_hier_inner_jit``), and the same body ``shard_map``'d across devices
+one program over ``[g, p_max, k]`` blocks on jax (``_hier_inner_jit``:
+small groups batched, large ones under ``lax.map``), and the same body ``shard_map``'d across devices
 under ``sharding="shard_map"`` so no device touches more than its
 ``ceil(g/ndev)`` blocks.  One group reproduces the flat path bit-identically
 (the outer trivially assigns it all ``n`` units and the inner IS the flat
@@ -214,34 +214,39 @@ the banked paths can sample-and-bank via
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import List, Optional, Sequence, Tuple, Union
+from typing import List, NamedTuple, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
 from .fpm import ConstantModel, PiecewiseLinearFPM
 
-__all__ = ["ModelBank", "aggregate_groups", "group_members"]
+__all__ = ["GroupIndex", "ModelBank", "aggregate_groups", "group_index", "group_members"]
 
 ArrayLike = Union[float, Sequence[float], np.ndarray]
+
+
+def _monotone_rows(xs: np.ndarray, ss: np.ndarray, counts: np.ndarray) -> np.ndarray:
+    """Per-row monotone-time flags ``[..., p]`` of a padded bank (see
+    :meth:`ModelBank.is_monotone`): knots positive and finite, knot times
+    ``x/s`` nondecreasing."""
+    k = xs.shape[-1]
+    pts = np.arange(k) < counts[..., None]
+    ok_pts = (xs > 0.0) & np.isfinite(xs) & (ss > 0.0) & np.isfinite(ss)
+    ok = ~np.any(pts & ~ok_pts, axis=-1)
+    if k >= 2:
+        x0, x1 = xs[..., :-1], xs[..., 1:]
+        s0, s1 = ss[..., :-1], ss[..., 1:]
+        seg = np.arange(k - 1) < (counts - 1)[..., None]
+        ok_seg = (x1 >= x0) & (x0 * s1 <= x1 * s0)
+        ok &= ~np.any(seg & ~ok_seg, axis=-1)
+    return ok
 
 
 def _monotone_check(xs: np.ndarray, ss: np.ndarray, counts: np.ndarray) -> bool:
     """Host-side monotone-time check over a padded bank (see
     :meth:`ModelBank.is_monotone`); one numpy pass, shared with the jax
     bank's host snapshot path."""
-    k = xs.shape[-1]
-    pts = np.arange(k) < counts[..., None]
-    ok_pts = (xs > 0.0) & np.isfinite(xs) & (ss > 0.0) & np.isfinite(ss)
-    if np.any(pts & ~ok_pts):
-        return False
-    if k >= 2:
-        x0, x1 = xs[..., :-1], xs[..., 1:]
-        s0, s1 = ss[..., :-1], ss[..., 1:]
-        seg = np.arange(k - 1) < (counts - 1)[..., None]
-        ok_seg = (x1 >= x0) & (x0 * s1 <= x1 * s0)
-        if np.any(seg & ~ok_seg):
-            return False
-    return True
+    return bool(np.all(_monotone_rows(xs, ss, counts)))
 
 
 @dataclass
@@ -509,36 +514,108 @@ class ModelBank:
 
 # ---------------------------------------------------------------------------
 # Group aggregation — the two-level partitioning path (core/hierarchy.py)
+#
+# Everything here works on all groups at once: a group is a row of a
+# ``[g, p_max]`` member-index block (``GroupIndex``), sample times are
+# ``[g, T]`` rows padded with ``inf``, and the member allocations are
+# ``[g, T, p_max]`` slabs.  Each group's row is bitwise what the same
+# expressions give for that group alone.
 # ---------------------------------------------------------------------------
 
 
-def _alloc_at_times(bank: ModelBank, ts: np.ndarray, caps: np.ndarray) -> np.ndarray:
-    """``alloc_at_time`` for a whole VECTOR of candidate times at once:
-    returns ``[T, p]``.  Expression-for-expression the scalar
-    :meth:`ModelBank.alloc_at_time` with a leading time axis (same shape
-    discipline as the jax ``_alloc_at_time``'s batched ``t``), so each row
-    is bitwise what the scalar call would produce — the group aggregation
-    samples K knots in three numpy passes instead of K."""
-    ts = np.asarray(ts, dtype=np.float64)[:, None]  # [T, 1]
-    caps2 = np.broadcast_to(np.asarray(caps, dtype=np.float64), (bank.p,))[None, :]
-    first_x, first_s, last_x, last_s = bank._edges()
+class GroupIndex(NamedTuple):
+    """A ``groups[p]`` assignment as arrays: group ``r`` (the ``r``-th
+    smallest id) holds the processors ``idx[r, :sizes[r]]``, ascending."""
 
-    best = np.minimum(ts * first_s[None, :], np.minimum(first_x[None, :], caps2))
+    gids: np.ndarray  # [g] sorted unique group ids
+    idx: np.ndarray  # [g, p_max] member processor indices, 0 where padded
+    mask: np.ndarray  # [g, p_max] True on members
+    sizes: np.ndarray  # [g]
 
-    k_max = bank.xs.shape[1]
+    @property
+    def g(self) -> int:
+        return int(self.sizes.shape[0])
+
+    @property
+    def p(self) -> int:
+        return int(self.sizes.sum())
+
+    @property
+    def members(self) -> List[np.ndarray]:
+        return [row[:s] for row, s in zip(self.idx, self.sizes.tolist())]
+
+    def gather(self, a: np.ndarray, fill=0) -> np.ndarray:
+        """``a[p, ...]`` as member blocks ``[g, p_max, ...]``, ``fill`` on
+        padded rows."""
+        out = a[self.idx]
+        mask = self.mask.reshape(self.mask.shape + (1,) * (a.ndim - 1))
+        return np.where(mask, out, np.asarray(fill, dtype=out.dtype))
+
+    def scatter(self, blocks: np.ndarray, p: int) -> np.ndarray:
+        """Member blocks ``[g, p_max]`` back to a ``[p]`` vector."""
+        out = np.zeros(p, dtype=blocks.dtype)
+        out[self.idx[self.mask]] = blocks[self.mask]
+        return out
+
+
+def group_index(groups: Sequence[int]) -> GroupIndex:
+    """Normalize a ``groups[p]`` assignment into member blocks."""
+    garr = np.asarray(groups)
+    if garr.ndim != 1:
+        raise ValueError("groups must be a 1-D per-processor assignment")
+    gids, inv = np.unique(garr, return_inverse=True)
+    g = int(gids.shape[0])
+    sizes = np.bincount(inv, minlength=g).astype(np.int64)
+    order = np.argsort(inv, kind="stable")  # ascending members per group
+    starts = np.cumsum(sizes) - sizes
+    pos = np.arange(garr.shape[0]) - starts[inv[order]]
+    p_max = max(int(sizes.max(initial=0)), 1)
+    idx = np.zeros((g, p_max), dtype=np.int64)
+    mask = np.zeros((g, p_max), dtype=bool)
+    idx[inv[order], pos] = order
+    mask[inv[order], pos] = True
+    return GroupIndex(gids.astype(np.int64), idx, mask, sizes)
+
+
+def group_members(groups: Sequence[int]) -> Tuple[List[int], List[np.ndarray]]:
+    """Normalize a ``groups[p]`` assignment: returns the sorted unique group
+    ids and, per group, the member processor indices in ascending order (the
+    order the hierarchical scatter preserves)."""
+    index = group_index(groups)
+    return index.gids.tolist(), index.members
+
+
+def _alloc_at_times_blocks(
+    xs: np.ndarray, ss: np.ndarray, counts: np.ndarray, caps: np.ndarray,
+    ts: np.ndarray,
+) -> np.ndarray:
+    """``alloc_at_time`` of ``[G, P, k]`` member blocks at per-group sample
+    times ``ts[G, T]``: returns ``[G, T, P]``.  Expression-for-expression
+    :meth:`ModelBank.alloc_at_time` with leading group and time axes, so each
+    element is bitwise what the single-row call gives."""
+    ts = np.asarray(ts, dtype=np.float64)[..., None]  # [G, T, 1]
+    caps2 = caps[:, None, :]  # [G, 1, P]
+    last = np.maximum(counts - 1, 0)
+    first_x, first_s = xs[:, None, :, 0], ss[:, None, :, 0]
+    last_x = np.take_along_axis(xs, last[..., None], axis=-1)[:, None, :, 0]
+    last_s = np.take_along_axis(ss, last[..., None], axis=-1)[:, None, :, 0]
+
+    best = np.minimum(ts * first_s, np.minimum(first_x, caps2))
+
+    k_max = xs.shape[-1]
     if k_max >= 2:
-        x0, x1 = bank.xs[None, :, :-1], bank.xs[None, :, 1:]
-        s0, s1 = bank.ss[None, :, :-1], bank.ss[None, :, 1:]
-        seg = np.arange(k_max - 1)[None, None, :]
+        x0, x1 = xs[:, None, :, :-1], xs[:, None, :, 1:]
+        s0, s1 = ss[:, None, :, :-1], ss[:, None, :, 1:]
+        seg = np.arange(k_max - 1)
         valid = (
-            (seg < (bank.counts - 1)[None, :, None])
+            (seg < (counts - 1)[:, None, :, None])
             & (x0 < caps2[..., None])
             & (x1 > x0)
         )
         x1c = np.minimum(x1, caps2[..., None])
         denom = np.where(x1 > x0, x1 - x0, 1.0)
         m = (s1 - s0) / denom
-        tseg = ts[..., None]  # [T, 1, 1]
+        tseg = ts[..., None]  # [G, T, 1, 1]
         a = 1.0 - tseg * m
         b = tseg * (s0 - m * x0)
         with np.errstate(divide="ignore", invalid="ignore"):
@@ -555,130 +632,189 @@ def _alloc_at_times(bank: ModelBank, ts: np.ndarray, caps: np.ndarray) -> np.nda
         cand = np.where(valid, cand, 0.0)
         best = np.maximum(best, cand.max(axis=-1))
 
-    ub_r = ts * last_s[None, :]
-    right = (caps2 > last_x[None, :]) & (ub_r >= last_x[None, :]) & (bank.counts > 0)[None, :]
+    ub_r = ts * last_s
+    nonempty = (counts > 0)[:, None, :]
+    right = (caps2 > last_x) & (ub_r >= last_x) & nonempty
     best = np.maximum(best, np.where(right, np.minimum(ub_r, caps2), 0.0))
 
-    best = np.where((caps2 > 0.0) & (bank.counts > 0)[None, :], best, 0.0)
+    best = np.where((caps2 > 0.0) & nonempty, best, 0.0)
     return np.where(ts > 0.0, best, 0.0)
 
 
-def group_members(groups: Sequence[int]) -> Tuple[List[int], List[np.ndarray]]:
-    """Normalize a ``groups[p]`` assignment: returns the sorted unique group
-    ids and, per group, the member processor indices in ascending order (the
-    order the hierarchical scatter preserves)."""
-    garr = np.asarray(groups)
-    if garr.ndim != 1:
-        raise ValueError("groups must be a 1-D per-processor assignment")
-    gids = sorted(set(int(v) for v in garr))
-    members = [np.flatnonzero(garr == g) for g in gids]
-    return gids, members
+def _alloc_at_times(bank: ModelBank, ts: np.ndarray, caps: np.ndarray) -> np.ndarray:
+    """``alloc_at_time`` of a whole bank at a vector of times: ``[T, p]``."""
+    caps = np.broadcast_to(np.asarray(caps, dtype=np.float64), (bank.p,))
+    return _alloc_at_times_blocks(
+        bank.xs[None], bank.ss[None], bank.counts[None], caps[None],
+        np.asarray(ts, dtype=np.float64)[None],
+    )[0]
 
 
-def _aggregate_one(
-    sub: ModelBank, caps: np.ndarray, max_knots: int
-) -> Tuple[List[float], List[float]]:
-    """One group's aggregate knots.
+def sum_members(a: np.ndarray, sizes: np.ndarray) -> np.ndarray:
+    """Sum the member axis (last) of ``[G, ..., p_max]`` blocks over each
+    group's ``sizes[r]`` members.  Groups of one size are summed together
+    as a contiguous ``[..., size]`` array, so each group's sum runs in the
+    same order as a sum over that group alone."""
+    out = np.zeros(a.shape[:-1], dtype=a.dtype)
+    for s in np.unique(sizes).tolist():
+        if s == 0:
+            continue
+        rows = np.flatnonzero(sizes == s)
+        out[rows] = np.ascontiguousarray(a[rows, ..., :s]).sum(axis=-1)
+    return out
 
-    The aggregate problem-size-at-time function is ``X(t) = sum_i
-    alloc_i(t, cap_i)`` — exactly what one bisection step of the outer
-    partitioner needs.  Knots are sampled at the union of the members'
-    observed knot times ``x_ij / s_ij`` plus each member's cap-crossing time
-    ``time_i(cap_i)`` (where its alloc saturates), so the aggregate is exact
-    at every time any member's behaviour changes slope; between knots the
-    bank's linear-in-speed interpolation approximates the true piecewise-
-    rational composition.  Member caps are baked in (NOT the job size ``n``:
-    the same aggregate serves any ``n``, and allocations above ``n`` cannot
-    occur at the solution).  Sampling at sorted times makes the result
-    monotone-time by construction: ``x0 s1 <= x1 s0`` with ``s = x/t``
-    reduces to ``t0 <= t1``.
 
-    Two refinements keep the interpolation honest between knot times:
+def _row_unique(a: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+    """Row-wise ``np.unique`` of ``[r, m]`` values whose absent entries are
+    ``inf``: sorted rows (``inf`` padded) and the count kept per row."""
+    a = np.sort(a, axis=1)
+    if a.shape[1] > 1:
+        dup = np.zeros(a.shape, dtype=bool)
+        dup[:, 1:] = a[:, 1:] == a[:, :-1]
+        a = np.sort(np.where(dup, np.inf, a), axis=1)
+    return a, np.isfinite(a).sum(axis=1)
 
-    * a member's alloc can JUMP at a knot time — within a segment the
-      implied time ``x / s(x)`` is a monotone hyperbola piece (``s``
-      linear), so when it runs *decreasing* the whole segment becomes
-      feasible the moment ``t`` reaches the far knot's time: a step, never
-      an interior extremum.  A sample exactly at the knot time lands on TOP
-      of that step; sampling each kept time again just below
-      (``t (1 - 1e-9)``) pins the step's bottom, so the aggregate brackets
-      the jump instead of interpolating across it;
-    * between WIDELY separated knot times the sum of hyperbola/linear
-      member pieces bends far from the bank's linear-in-speed
-      interpolation, so a geometric fill of sample times spans the whole
-      knot range — the gap ratio is bounded regardless of how the members'
-      knots cluster.
 
-    The knot budget splits ``max_knots`` as: up to 1/4 exact knot times,
-    1/4 geometric fill, then the below-jump brackets double the kept set.
+def _linspace_rows(start: np.ndarray, stop: np.ndarray, num: int) -> np.ndarray:
+    """``np.linspace(start[r], stop[r], num)`` for every row ``r``, in the
+    scalar call's own arithmetic (``num >= 2``)."""
+    div = num - 1
+    delta = stop - start
+    step = delta / div
+    y = np.arange(0, num, dtype=np.float64)[None, :]
+    y = np.where((step == 0)[:, None], (y / div) * delta[:, None], y * step[:, None])
+    y = y + start[:, None]
+    y[:, -1] = stop
+    return y
+
+
+def _geomspace_rows(start: np.ndarray, stop: np.ndarray, num: int) -> np.ndarray:
+    """``np.geomspace(start[r], stop[r], num)`` for positive rows, in the
+    scalar call's own arithmetic (``num >= 2``)."""
+    y = np.power(10.0, _linspace_rows(np.log10(start), np.log10(stop), num))
+    y[:, 0] = start
+    y[:, -1] = stop
+    return y
+
+
+def aggregate_width(max_knots: int) -> int:
+    """The most knots one group's aggregate can have: ``quota`` sampled
+    times, a bracket below each, and ``quota`` geometric fills."""
+    return 3 * max(max_knots // 4, 2)
+
+
+def aggregate_times(
+    bank: ModelBank, index: GroupIndex, caps: np.ndarray, max_knots: int
+) -> Tuple[np.ndarray, np.ndarray]:
+    """Sample times of every group's aggregate (see :func:`aggregate_groups`):
+    ``(ts [g, aggregate_width(max_knots)], count [g])``, each row sorted,
+    strictly positive and right-padded by repeating its last time (1.0 on
+    a row with none), so every padded column evaluates to a sample that is
+    then dropped.
+
+    A group's times are the distinct finite positive knot times ``x/s`` of
+    its members with a positive cap, plus each such member's cap-crossing
+    time ``time_i(cap_i)``; above ``quota = max(max_knots // 4, 2)`` of
+    them, ``quota`` are kept at the rounded positions of
+    ``linspace(0, count - 1, quota)``; each kept time is joined by
+    ``t (1 - 1e-9)``, just below it; and when the row spans an interval,
+    ``geomspace(first, last, quota)`` is added.
     """
-    ts = _aggregate_times(sub, caps, max_knots)
-    if ts.size == 0:
-        return [], []
-    caps_f = caps.astype(np.float64)
-    k = sub.xs.shape[1]
-    # _alloc_at_times materializes ~a dozen [T_chunk, p, k-1] temporaries;
-    # chunk the time axis so each slab stays ~1 MB and the whole working set
-    # L2-resident — the pass is memory-bandwidth bound, and cache blocking
-    # here measures ~1.8x at fleet group shapes (p_g=1000, k~17) while also
-    # keeping p ~ 10^5 member groups allocatable at p=10^6.  Chunk
-    # boundaries cannot change any element's arithmetic, so the result is
-    # bitwise independent of the chunk size.
-    t_chunk = max(1, int(131_072 // max(sub.p * max(k, 1), 1)))
-    xs_g = np.concatenate(
-        [
-            _alloc_at_times(sub, ts[i : i + t_chunk], caps_f).sum(axis=1)
-            for i in range(0, ts.size, t_chunk)
-        ]
-    )
-    return _points_from_samples(ts, xs_g)
-
-
-def _aggregate_times(sub: ModelBank, caps: np.ndarray, max_knots: int) -> np.ndarray:
-    """Sample times for one group's aggregate (see :func:`_aggregate_one`).
-
-    Factored out of :func:`_aggregate_one` so the jax hierarchy backend can
-    compute the sample grid on host (cheap, O(p k) with small constants)
-    while evaluating the member allocations at those times on device.
-    Returns a sorted, strictly positive, possibly empty float array.
-    """
-    k = sub.xs.shape[1]
-    valid = (np.arange(k)[None, :] < sub.counts[:, None]) & (caps[:, None] > 0)
-    with np.errstate(divide="ignore", invalid="ignore"):
-        t_pts = np.where(sub.ss > 0, sub.xs / sub.ss, np.nan)
-    ts = t_pts[valid]
-    active = (caps > 0) & (sub.counts > 0)
-    if np.any(active):
-        cap_t = sub.time(np.where(active, caps, 1.0))
-        ts = np.concatenate([ts, cap_t[active]])
-    ts = np.unique(ts[np.isfinite(ts) & (ts > 0)])
-    if ts.size == 0:
-        return ts
+    g = index.g
+    width = aggregate_width(max_knots)
+    if g == 0:
+        return np.ones((0, width)), np.zeros(0, dtype=np.int64)
     quota = max(max_knots // 4, 2)
-    if ts.size > quota:
-        pick = np.unique(np.round(np.linspace(0, ts.size - 1, quota)).astype(int))
-        ts = ts[pick]
-    # Jump brackets FIRST, on the knot/cap-derived times only: member alloc
-    # functions can step exactly AT a knot time, never between knots, so the
-    # geometric fill below (curvature sampling in wide gaps) needs no
-    # brackets — skipping them keeps the sampled grid (and the group bank's
-    # knot count) ~25% smaller for the same accuracy.
-    ts = np.unique(np.concatenate([ts, ts * (1.0 - 1e-9)]))
-    if ts[-1] > ts[0]:
-        ts = np.unique(np.concatenate([ts, np.geomspace(ts[0], ts[-1], quota)]))
-    return ts
+    caps = np.asarray(caps, dtype=np.float64)
+    kc = max(int(bank.counts.max(initial=0)), 1)
+    xs, ss = bank.xs[:, :kc], bank.ss[:, :kc]
+    with np.errstate(divide="ignore", invalid="ignore"):
+        t_pts = np.where(ss > 0, xs / ss, np.nan)
+    valid = (np.arange(kc)[None, :] < bank.counts[:, None]) & (caps[:, None] > 0)
+    active = (caps > 0) & (bank.counts > 0)
+    with np.errstate(divide="ignore", invalid="ignore"):
+        cap_t = bank.time(np.where(active, caps, 1.0))
+    cand = np.concatenate(
+        [np.where(valid, t_pts, np.nan), np.where(active, cap_t, np.nan)[:, None]], axis=1
+    )
+    cand = np.where(np.isfinite(cand) & (cand > 0), cand, np.inf)
+    ts, cnt = _row_unique(index.gather(cand, np.inf).reshape(g, -1))
+    ts = ts[:, : max(int(cnt.max(initial=0)), quota)]
+
+    big = np.flatnonzero(cnt > quota)
+    if big.size:
+        lin = _linspace_rows(np.zeros(big.size), (cnt[big] - 1).astype(np.float64), quota)
+        pick = np.round(lin).astype(int)
+        ts[big, :quota] = np.take_along_axis(ts[big], pick, axis=1)
+    ts = ts[:, :quota]
+    cnt = np.minimum(cnt, quota)
+
+    ts, cnt = _row_unique(np.concatenate([ts, ts * (1.0 - 1e-9)], axis=1))
+    rows = np.arange(g)
+    first, last = ts[:, 0], ts[rows, np.maximum(cnt - 1, 0)]
+    fill = np.flatnonzero((cnt > 0) & (last > first))
+    geo = np.full((g, quota), np.inf)
+    if fill.size:
+        geo[fill] = _geomspace_rows(first[fill], last[fill], quota)
+    ts, cnt = _row_unique(np.concatenate([ts, geo], axis=1))
+    ts = np.concatenate([ts, np.full((g, width), np.inf)], axis=1)[:, :width]
+    pad = np.where(cnt > 0, ts[rows, np.maximum(cnt - 1, 0)], 1.0)
+    ts = np.where(np.arange(width)[None, :] < cnt[:, None], ts, pad[:, None])
+    return ts, cnt.astype(np.int64)
 
 
-def _points_from_samples(
-    ts: np.ndarray, xs_g: np.ndarray
-) -> Tuple[List[float], List[float]]:
-    """Turn sampled ``(time, aggregate size)`` pairs into bank knot lists."""
-    keep = xs_g > 0
-    # equal-X plateaus (all members capped): keep the FIRST (earliest-time,
-    # fastest) occurrence — the true aggregate reaches that size then.
-    keep &= np.concatenate([[True], np.diff(xs_g) > 0])
-    ts, xs_g = ts[keep], xs_g[keep]
-    return list(xs_g), list(xs_g / ts)
+def aggregate_allocs_host(
+    bank: ModelBank, index: GroupIndex, caps: np.ndarray, ts: np.ndarray
+) -> np.ndarray:
+    """Each group's summed member allocation at its sample times, ``[g, T]``.
+
+    The pass materializes ~a dozen ``[G, T, P, k-1]`` temporaries, so it runs
+    in slabs of ~1 MB: as many whole groups as fit, or, for a group too
+    large for one slab, a few of its sample times at a time.  Slab edges
+    change no element's arithmetic."""
+    g, width = ts.shape
+    p_max = index.idx.shape[1]
+    k = bank.xs.shape[1]
+    budget = 131_072
+    g_chunk = max(1, budget // max(width * p_max * k, 1))
+    t_chunk = width if g_chunk > 1 else max(1, budget // max(p_max * k, 1))
+    caps = np.asarray(caps, dtype=np.float64)
+    out = np.zeros((g, width), dtype=np.float64)
+    for g0 in range(0, g, g_chunk):
+        sl = slice(g0, min(g0 + g_chunk, g))
+        sub = GroupIndex(index.gids[sl], index.idx[sl], index.mask[sl], index.sizes[sl])
+        xs_b, ss_b = sub.gather(bank.xs), sub.gather(bank.ss)
+        counts_b, caps_b = sub.gather(bank.counts), sub.gather(caps)
+        for t0 in range(0, width, t_chunk):
+            tl = slice(t0, min(t0 + t_chunk, width))
+            alloc = _alloc_at_times_blocks(xs_b, ss_b, counts_b, caps_b, ts[sl, tl])
+            out[sl, tl] = sum_members(alloc, sub.sizes)
+    return out
+
+
+def aggregate_bank(ts: np.ndarray, count: np.ndarray, xsum: np.ndarray) -> ModelBank:
+    """The ``[g, k_g]`` group bank from sampled ``(time, aggregate size)``
+    rows: a sample is a knot where the aggregate is positive and larger
+    than at the previous sample (on an equal-size plateau, where every
+    member is capped, the first and fastest one is kept); its speed is
+    size over time.  Monotone-time by construction."""
+    g, width = ts.shape
+    keep = (np.arange(width)[None, :] < count[:, None]) & (xsum > 0)
+    if width > 1:
+        keep[:, 1:] &= (xsum[:, 1:] - xsum[:, :-1]) > 0
+    counts = keep.sum(axis=1).astype(np.int64)
+    k = max(int(counts.max(initial=1)), 1)
+    order = np.argsort(~keep, axis=1, kind="stable")[:, :k]
+    xk = np.take_along_axis(xsum, order, axis=1)
+    tk = np.take_along_axis(ts, order, axis=1)
+    last = np.maximum(counts - 1, 0)[:, None]
+    col = np.arange(k)[None, :]
+    xk = np.where(col < counts[:, None], xk, np.take_along_axis(xk, last, axis=1))
+    tk = np.where(col < counts[:, None], tk, np.take_along_axis(tk, last, axis=1))
+    empty = (counts == 0)[:, None]
+    xs = np.where(empty, 0.0, xk)
+    ss = np.where(empty, 0.0, xk / tk)
+    return ModelBank(xs=xs, ss=ss, counts=counts, monotone=True)
 
 
 def aggregate_groups(
@@ -690,24 +826,43 @@ def aggregate_groups(
 ) -> Tuple[ModelBank, np.ndarray, List[np.ndarray]]:
     """Build the ``[g, k_g]`` group-level bank for a ``groups[p]`` assignment.
 
+    A group's aggregate problem-size-at-time function is ``X(t) = sum_i
+    alloc_i(t, cap_i)`` — exactly what one bisection step of the outer
+    partitioner needs.  It is sampled at the times of
+    :func:`aggregate_times`: the members' knot times ``x_ij / s_ij`` and
+    cap-crossing times ``time_i(cap_i)`` (where a member's alloc saturates),
+    so the aggregate is exact at every time any member's behaviour changes
+    slope; between knots the bank's linear-in-speed interpolation
+    approximates the true piecewise-rational composition.  Member caps are
+    baked in (NOT the job size ``n``: the same aggregate serves any ``n``).
+    Sampling at sorted times makes the result monotone-time by
+    construction: ``x0 s1 <= x1 s0`` with ``s = x/t`` reduces to
+    ``t0 <= t1``.
+
+    Two refinements keep the interpolation honest between knot times:
+
+    * a member's alloc can JUMP at a knot time — within a segment the
+      implied time ``x / s(x)`` is a monotone hyperbola piece (``s``
+      linear), so when it runs *decreasing* the whole segment becomes
+      feasible the moment ``t`` reaches the far knot's time: a step, never
+      an interior extremum.  A sample exactly at the knot time lands on TOP
+      of that step; sampling each kept time again just below
+      (``t (1 - 1e-9)``) pins the step's bottom;
+    * between WIDELY separated knot times the sum of member pieces bends far
+      from the interpolation, so a geometric fill of sample times spans the
+      whole knot range.
+
     Returns ``(group_bank, group_caps, members)``: one aggregate row per
-    group (see :func:`_aggregate_one`; ``max_group_knots`` bounds each row's
-    knot count, keeping the outer solve O(g k_g)), the summed member caps,
-    and the per-group member indices.  The group bank's ``monotone`` flag is
-    set — true by construction — so the outer integer completion may always
-    take the threshold-count bulk grant.  Groups with no capacity get an
-    empty row and cap 0 (the outer solver allocates them nothing).
+    group (``max_group_knots`` bounds each row's knot count through the
+    quota, keeping the outer solve O(g k_g)), the summed member caps, and
+    the per-group member indices.  Groups with no capacity get an empty
+    row and cap 0 (the outer solver allocates them nothing).
     """
     caps_arr = np.broadcast_to(np.asarray(caps, dtype=np.float64), (bank.p,))
-    gids, members = group_members(groups)
-    pts: List[Tuple[List[float], List[float]]] = []
-    gcaps = np.zeros(len(gids), dtype=np.float64)
-    for gi, idx in enumerate(members):
-        sub = ModelBank(
-            xs=bank.xs[idx], ss=bank.ss[idx], counts=bank.counts[idx]
-        )
-        gcaps[gi] = caps_arr[idx].sum()
-        pts.append(_aggregate_one(sub, caps_arr[idx], max_group_knots))
-    gbank = ModelBank.from_point_lists(pts)
-    gbank.monotone = True  # by construction: knots sampled at sorted times
-    return gbank, gcaps, members
+    index = group_index(groups)
+    ts, count = aggregate_times(bank, index, caps_arr, max_group_knots)
+    gbank = aggregate_bank(
+        ts, count, aggregate_allocs_host(bank, index, caps_arr, ts)
+    )
+    gcaps = sum_members(index.gather(caps_arr), index.sizes)
+    return gbank, gcaps, index.members

@@ -630,16 +630,15 @@ _partition_units_jit = partial(
 
 # ---------------------------------------------------------------------------
 # Hierarchical inner solves: one device program over [g, p_max, k] group
-# blocks, with SIZE-ROUTED execution.  When the whole block set fits in
-# cache the groups run BATCHED (one [g, ...] bisection — every loop update
-# is already masked per lane, so results are bit-identical to solo runs);
-# when it does not, lax.map runs the groups SEQUENTIALLY so each group's
-# [p_g, k] block stays cache-resident through its whole t* bisection — the
-# cache-blocking that recovers the p >= 10^4 stacked regression.  Either
-# way the program compiles once and dispatches once.  Under shard_map the
-# same body runs per device over its local group lanes (no collectives:
-# every group's solve is independent), so no single device ever touches
-# more than its ceil(g/ndev) blocks of the bank.
+# blocks, with SIZE-ROUTED execution (``Hierarchy._inner_serial``).  Small
+# groups run BATCHED (one [g, ...] bisection — every loop update is already
+# masked per lane, so results are bit-identical to solo runs); past a few MB
+# a group, lax.map runs the groups SEQUENTIALLY, each group's [p_g, k] block
+# through its whole t* bisection.  Either way the program compiles once and
+# dispatches once.  Under shard_map the same body runs per device over its
+# local group lanes (no collectives: every group's solve is independent), so
+# no single device ever touches more than its ceil(g/ndev) blocks of the
+# bank.
 # ---------------------------------------------------------------------------
 
 
@@ -651,9 +650,9 @@ def _hier_inner_map(
     (members right-padded with caps=0 / min_units=0 rows), ``n`` ``[g]`` the
     outer solve's group shares, ``min_units`` ``[g, p_max]``, ``fast_mask``
     ``[g]`` the per-group completion routing.  ``serial`` picks lax.map
-    (cache-blocked, for block sets larger than cache) over the batched
-    solve (one masked bisection, for cache-resident block sets) — the two
-    return BIT-IDENTICAL allocations, see the routing note above.  Returns
+    (one group at a time, for large groups) over the batched solve (one
+    masked bisection, for small groups) — the two return BIT-IDENTICAL
+    allocations, see the routing note above.  Returns
     ``(d [g, p_max], ok [g], t_star [g])``."""
     if not serial:
         return _partition_units_impl(
@@ -676,6 +675,20 @@ def _hier_inner_map(
 _hier_inner_jit = partial(
     jax.jit, static_argnames=("rel_tol", "max_steps", "completion_fast", "serial")
 )(_hier_inner_map)
+
+
+@jax.jit
+def _gather_blocks_jit(xs, ss, counts, idx, mask):
+    """The ``[g, p_max, k]`` member blocks of a ``[p, k]`` bank for member
+    indices ``idx[g, p_max]``; padded rows (``mask`` False) are zeros with
+    count 0."""
+    zero = jnp.zeros((), xs.dtype)
+    m3 = mask[..., None]
+    return (
+        jnp.where(m3, xs[idx], zero),
+        jnp.where(m3, ss[idx], zero),
+        jnp.where(mask, counts[idx], jnp.zeros((), counts.dtype)),
+    )
 
 
 def _fold_in_impl(xs, ss, counts, x, s, valid):

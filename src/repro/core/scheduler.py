@@ -70,7 +70,7 @@ import numpy as np
 from .executor import BatchedSimulatedExecutor2D, Executor
 from .fpm import AnalyticModel, PiecewiseLinearFPM, imbalance
 from .hierarchy import Hierarchy
-from .modelbank import ModelBank
+from .modelbank import ModelBank, group_index
 from .partition2d import _col_times, _flat_imbalance, _rebalance_widths
 from .speedstore import SpeedStore
 
@@ -233,6 +233,7 @@ class Scheduler:
         self.groups = (
             [int(v) for v in groups] if groups is not None else None
         )
+        self._group_index = None  # (groups list, its GroupIndex)
         self.sharding = sharding
         self.max_group_knots = int(max_group_knots)
         # online state
@@ -324,14 +325,29 @@ class Scheduler:
                 "hierarchical partitioning requires a banked store "
                 '(backend "numpy" or "jax")'
             )
-        h = Hierarchy.from_bank(
-            self.store.bank(),
-            self.groups,
+        tel = _obs_active()
+        rec = tel is not None and tel.enabled
+        if rec:
+            tok = tel.begin("scheduler.hier_bank")
+        cached = self._group_index
+        if cached is None or cached[0] is not self.groups:
+            cached = self._group_index = (self.groups, group_index(self.groups))
+        kw = dict(
             backend="jax" if self.backend == "jax" else "numpy",
             sharding=self.sharding,
             max_group_knots=self.max_group_knots,
             dtype=self.dtype,
         )
+        if self.backend == "jax":
+            # the device carry, read once: the inner blocks are gathered
+            # from it on the device
+            h = Hierarchy.from_device_bank(
+                self.store.device_bank(snapshot=False), cached[1], **kw
+            )
+        else:
+            h = Hierarchy.from_bank(self.store.bank(), cached[1], **kw)
+        if rec:
+            tel.end(tok)
         return h.partition_units(
             n, caps, min_units=mu,
             completion=self._completion_for(self.store), with_t=True,
@@ -536,7 +552,8 @@ class Scheduler:
              store already holds estimates), gather times;
           2. imbalance <= eps -> done;
           3. fold observations into the partial FPM estimates;
-          4. re-partition optimally for the current estimates, execute,
+          4. re-partition optimally for the current estimates (through the
+             two-level ``Hierarchy`` when the scheduler has ``groups``), execute,
              measure; goto 3 — with the deterministic local-probe escape
              when the partitioner reaches a fixed point short of eps.
 
@@ -564,6 +581,11 @@ class Scheduler:
             )
         store = self.store
         models = store.models
+        if self.groups is not None and len(self.groups) != p:
+            raise ValueError(
+                f"groups must be a length-p assignment "
+                f"(got {len(self.groups)} for p={p})"
+            )
 
         history: List[Tuple[List[int], List[float]]] = []
         seen: Dict[Tuple[int, ...], List[float]] = {}
@@ -590,6 +612,8 @@ class Scheduler:
             return list(times)
 
         def repartition() -> List[int]:
+            if self.groups is not None:  # the two-level route
+                return self._hier_partition(n, caps, mu)[0]
             return store.partition_units(
                 n, caps, min_units=mu, completion=self._completion_for(store)
             )
@@ -636,6 +660,7 @@ class Scheduler:
                     "history": history,
                     "models": models,
                     "probes_used": probe_budget - probes_left,
+                    "route": "flat" if self.groups is None else "hier",
                 },
             )
 

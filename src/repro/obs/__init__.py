@@ -60,8 +60,13 @@ What gets recorded (the instrumented layers):
   the power-cap theta gauge, and every
   :meth:`~repro.fleet.scheduler.FleetScheduler.stats` field as a
   ``fleet.*`` gauge each round;
-* ``Hierarchy`` — aggregation-cache hit/miss counters and outer/inner
-  solve spans;
+* ``Hierarchy`` — aggregation-cache hit/miss counters, the outer solve
+  (``hier.outer``, with ``hier.aggregate`` and ``hier.outer.complete``, and
+  the ``hier.outer.leftover`` counter of units granted one by one) and the
+  inner solves (``hier.inner``, with ``hier.inner.wait``: dispatch of the
+  inner program until its result is on the host); ``Scheduler``'s
+  two-level route reads its bank and slices the groups under
+  ``scheduler.hier_bank``;
 * ``StragglerDetector`` — ``straggler.strike`` events carrying the
   (predicted, observed, ratio) evidence and ``straggler.verdict`` events
   for REPROFILE/QUARANTINE;
